@@ -22,6 +22,9 @@ CPU-simulated fleets (docs/multihost.md):
   the compressed exchange exists for);
 * ``compressed_psum`` over the pod axis: quantization rel-err and wire
   ratio for the in-process collective the fleet exchange mirrors.
+
+Its child processes are CPU-forced on purpose: the fleet arm is a CPU
+simulation and reports no device speed.
 """
 from __future__ import annotations
 

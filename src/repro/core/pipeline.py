@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import (
     AsyncPipelineConfig,
@@ -210,7 +210,12 @@ def build_pipeline(
 
     key = jax.random.PRNGKey(seed)
     k_actor, k_critic, k_run = jax.random.split(key, 3)
-    actor_params = model.init(k_actor)
+    # Data-parallel placement: every device of the mesh holds the whole
+    # actor, reference and optimizer state (replicated), and the batch is
+    # split over the data axes. FSDP layouts (sharding.param_specs) are
+    # applied only when a checkpoint is restored onto a mesh.
+    replicated = NamedSharding(mesh, PartitionSpec())
+    actor_params = jax.device_put(model.init(k_actor), replicated)
     ref_params = jax.tree.map(jnp.copy, actor_params)  # frozen reference
 
     ctx = WorkerContext(
@@ -225,14 +230,16 @@ def build_pipeline(
             seed=seed,
             prefetch=coordinator.prefetch,
         ),
-        actor_state=trainer.init_state(actor_params),
+        actor_state=jax.device_put(trainer.init_state(actor_params),
+                                   replicated),
         ref_params=ref_params,
         tokenizer=tok,
         key=k_run,
         algorithm=spec,
     )
     if spec.uses_critic:
-        ctx.critic_state = trainer.init_state(critic_mod.init(cfg, k_critic))
+        ctx.critic_state = jax.device_put(
+            trainer.init_state(critic_mod.init(cfg, k_critic)), replicated)
     ctx.env = env_runtime
 
     if distributed is not None and distributed.enabled:

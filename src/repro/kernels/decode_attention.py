@@ -9,6 +9,14 @@ that shards of a sequence-sharded cache can be combined exactly with
 
 cache_len is a scalar-prefetch operand ((B,) int32): number of valid slots
 per sequence; ``pos_offset`` is the absolute position of local cache slot 0.
+
+Layouts: the caches keep their (B, S, KVH, D) / (P, page_size, KVH, D) shape
+and the wrappers view them lane-folded, (…, S, KVH*D) — a free reshape, no
+per-step transpose of the cache — so each K/V tile is a (block_s, D) slab
+picked by the kv-head index along the last axis. Mosaic needs a tile's last
+two dims divisible by (sublanes, 128) or equal to the array's, so compiled
+runs need ``D % 128 == 0`` and S blocks a multiple of the dtype's sublane
+count (8 for f32, 16 for bf16, 32 for int8); interpret mode takes any D.
 """
 from __future__ import annotations
 
@@ -20,21 +28,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import check_lane_width
+
 NEG_INF = -1e30
 LANES = 128
 
 
-def _pick_block_s(S: int, want: int) -> int:
-    """Largest divisor of ``S`` that is <= ``want``.
+def _pick_block_s(S: int, want: int, align: int = 1) -> int:
+    """Largest divisor of ``S`` that is <= ``want`` and a multiple of
+    ``align``; ``S`` itself when there is none (a whole-axis block is always
+    a legal tile).
 
     Arena widths are not always powers of two (prompt_len + max_new from a
     workload spec, e.g. S=160); asserting divisibility made those shapes hard
     failures. Falling back to the largest divisor keeps the grid exact —
     every position is covered exactly once, no padding tile."""
     bs = max(1, min(want, S))
-    while S % bs:
+    while bs and (S % bs or bs % align):
         bs -= 1
-    return bs
+    return bs or S
+
+
+def sublanes(dtype) -> int:
+    """Rows of one native TPU tile for ``dtype``: 8 x 32-bit, 16 x 16-bit,
+    32 x 8-bit."""
+    return 32 // jnp.dtype(dtype).itemsize
 
 
 def _ragged_block_index(si, lens_b, *, block_s: int, num_blocks: int,
@@ -100,9 +118,9 @@ def _kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0, :, :]  # (group, D)
-        k = k_ref[0, :, 0, :]  # (block_s, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[...]  # (group, D)
+        k = k_ref[...]  # (block_s, D)
+        v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -131,9 +149,13 @@ def _kernel(
 
     @pl.when(si == num_s_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-20)
-        o_ref[0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :] = (m_ref[:, 0] + jnp.log(l[:, 0]))
+        _emit(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+
+
+def _emit(o_ref, lse_ref, acc_ref, m_ref, l_ref):
+    l = jnp.maximum(l_ref[:, :1], 1e-20)
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    lse_ref[...] = m_ref[:, :1] + jnp.log(l)  # (group, 1)
 
 
 def _quant_kernel(
@@ -162,6 +184,7 @@ def _quant_kernel(
     whole point of ``ModelConfig.kv_quant``. Math otherwise identical to
     :func:`_kernel`."""
     b = pl.program_id(0)
+    kh = pl.program_id(1)
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -178,10 +201,18 @@ def _quant_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0, :, :]  # (group, D)
+        q = q_ref[...]  # (group, D)
+        # the scale tiles hold every kv head, (block_s, KVH): pick this
+        # head's column with a one-hot lane reduce
+        lane = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape, 1)
+
+        def head_col(ref):
+            return jnp.sum(jnp.where(lane == kh, ref[...], 0.0), axis=1,
+                           keepdims=True)
+
         # fused per-tile dequant: (block_s, D) int8 * (block_s, 1) f32
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
+        k = k_ref[...].astype(jnp.float32) * head_col(ks_ref)
+        v = v_ref[...].astype(jnp.float32) * head_col(vs_ref)
         s = jax.lax.dot_general(
             q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -211,9 +242,41 @@ def _quant_kernel(
 
     @pl.when(si == num_s_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-20)
-        o_ref[0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :] = (m_ref[:, 0] + jnp.log(l[:, 0]))
+        _emit(o_ref, lse_ref, acc_ref, m_ref, l_ref)
+
+
+def _decode_call(kernel, *, grid, num_scalar_prefetch, q_map, kv_specs,
+                 B, KVH, group, D, dtype, interpret):
+    """pallas_call shared by the three decode kernels: q and o are
+    (B, KVH, group, D) tiles of ``group`` heads, lse is (B, KVH, group, 1),
+    and the online-softmax state lives in VMEM scratch."""
+    q_spec = pl.BlockSpec((None, None, group, D), q_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch,
+        grid=grid,
+        in_specs=[q_spec] + kv_specs,
+        out_specs=[q_spec, pl.BlockSpec((None, None, group, 1), q_map)],
+        scratch_shapes=[
+            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((group, LANES), jnp.float32),
+            pltpu.VMEM((group, LANES), jnp.float32),
+        ],
+    )
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, KVH, group, D), dtype),
+            jax.ShapeDtypeStruct((B, KVH, group, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+
+    def run(*args):
+        o, lse = call(*args)
+        return o.reshape(B, KVH * group, D), lse.reshape(B, KVH * group)
+
+    return run
 
 
 def decode_attention_quant(
@@ -238,11 +301,11 @@ def decode_attention_quant(
     B, H, D = q.shape
     _, S, KVH, _ = k.shape
     assert H % KVH == 0
+    check_lane_width(D, interpret)
     group = H // KVH
     scale = scale if scale is not None else 1.0 / (D**0.5)
-    block_s = _pick_block_s(S, block_s)
+    block_s = _pick_block_s(S, block_s, sublanes(k.dtype))
     ns = S // block_s
-    qg = q.reshape(B, KVH, group, D)
 
     kernel = functools.partial(
         _quant_kernel,
@@ -257,38 +320,18 @@ def decode_attention_quant(
         _ragged_block_index, block_s=block_s, num_blocks=ns,
         pos_offset=pos_offset, window=window,
     )
-    kv_map = lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh, 0)
-    sc_map = lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KVH, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, kh, si, lens: (b, kh, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, D), kv_map),
-            pl.BlockSpec((1, block_s, 1, D), kv_map),
-            pl.BlockSpec((1, block_s, 1), sc_map),
-            pl.BlockSpec((1, block_s, 1), sc_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, group, D), lambda b, kh, si, lens: (b * KVH + kh, 0, 0)),
-            pl.BlockSpec((1, group), lambda b, kh, si, lens: (b * KVH + kh, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KVH, group, D), q.dtype),
-            jax.ShapeDtypeStruct((B * KVH, group), jnp.float32),
-        ],
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), qg, k, v, k_scale, v_scale)
-    return o.reshape(B, H, D), lse.reshape(B, H)
+    kv_spec = pl.BlockSpec(
+        (None, block_s, D), lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh))
+    sc_spec = pl.BlockSpec(
+        (None, block_s, KVH), lambda b, kh, si, lens: (b, ragged(si, lens[b]), 0))
+    run = _decode_call(
+        kernel, grid=(B, KVH, ns), num_scalar_prefetch=1,
+        q_map=lambda b, kh, si, lens: (b, kh, 0, 0),
+        kv_specs=[kv_spec, kv_spec, sc_spec, sc_spec],
+        B=B, KVH=KVH, group=group, D=D, dtype=q.dtype, interpret=interpret)
+    return run(cache_len.astype(jnp.int32), q.reshape(B, KVH, group, D),
+               k.reshape(B, S, KVH * D), v.reshape(B, S, KVH * D),
+               k_scale, v_scale)
 
 
 def decode_attention(
@@ -307,12 +350,11 @@ def decode_attention(
     B, H, D = q.shape
     _, S, KVH, _ = k.shape
     assert H % KVH == 0
+    check_lane_width(D, interpret)
     group = H // KVH
     scale = scale if scale is not None else 1.0 / (D**0.5)
-    block_s = _pick_block_s(S, block_s)
+    block_s = _pick_block_s(S, block_s, sublanes(k.dtype))
     ns = S // block_s
-    # reshape q to (B, KVH, group, D): heads are kv-major contiguous
-    qg = q.reshape(B, KVH, group, D)
 
     kernel = functools.partial(
         _kernel,
@@ -327,35 +369,16 @@ def decode_attention(
         _ragged_block_index, block_s=block_s, num_blocks=ns,
         pos_offset=pos_offset, window=window,
     )
-    kv_map = lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KVH, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, kh, si, lens: (b, kh, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, D), kv_map),
-            pl.BlockSpec((1, block_s, 1, D), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, group, D), lambda b, kh, si, lens: (b * KVH + kh, 0, 0)),
-            pl.BlockSpec((1, group), lambda b, kh, si, lens: (b * KVH + kh, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KVH, group, D), q.dtype),
-            jax.ShapeDtypeStruct((B * KVH, group), jnp.float32),
-        ],
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), qg, k, v)
-    return o.reshape(B, H, D), lse.reshape(B, H)
+    kv_spec = pl.BlockSpec(
+        (None, block_s, D), lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh))
+    run = _decode_call(
+        kernel, grid=(B, KVH, ns), num_scalar_prefetch=1,
+        q_map=lambda b, kh, si, lens: (b, kh, 0, 0),
+        kv_specs=[kv_spec, kv_spec],
+        B=B, KVH=KVH, group=group, D=D, dtype=q.dtype, interpret=interpret)
+    # q heads are kv-major contiguous: (B, H, D) -> (B, KVH, group, D)
+    return run(cache_len.astype(jnp.int32), q.reshape(B, KVH, group, D),
+               k.reshape(B, S, KVH * D), v.reshape(B, S, KVH * D))
 
 
 def _paged_kernel(
@@ -413,9 +436,9 @@ def paged_decode_attention(
     P, ps, KVH, _ = pool_k.shape
     T = tables.shape[1]
     assert H % KVH == 0
+    check_lane_width(D, interpret)
     group = H // KVH
     scale = scale if scale is not None else 1.0 / (D**0.5)
-    qg = q.reshape(B, KVH, group, D)
 
     kernel = functools.partial(
         _paged_kernel,
@@ -427,36 +450,14 @@ def paged_decode_attention(
 
     def kv_map(b, kh, ti, lens, tbl):
         last = jnp.clip((lens[b] + ps - 1) // ps - 1, 0, T - 1)
-        return (tbl[b, jnp.minimum(ti, last)], 0, kh, 0)
+        return (tbl[b, jnp.minimum(ti, last)], 0, kh)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KVH, T),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D),
-                         lambda b, kh, ti, lens, tbl: (b, kh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, group, D),
-                         lambda b, kh, ti, lens, tbl: (b * KVH + kh, 0, 0)),
-            pl.BlockSpec((1, group),
-                         lambda b, kh, ti, lens, tbl: (b * KVH + kh, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
-    )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * KVH, group, D), q.dtype),
-            jax.ShapeDtypeStruct((B * KVH, group), jnp.float32),
-        ],
-        interpret=interpret,
-    )(kv_len.astype(jnp.int32), tables.astype(jnp.int32), qg, pool_k, pool_v)
-    return o.reshape(B, H, D), lse.reshape(B, H)
+    kv_spec = pl.BlockSpec((None, ps, D), kv_map)
+    run = _decode_call(
+        kernel, grid=(B, KVH, T), num_scalar_prefetch=2,
+        q_map=lambda b, kh, ti, lens, tbl: (b, kh, 0, 0),
+        kv_specs=[kv_spec, kv_spec],
+        B=B, KVH=KVH, group=group, D=D, dtype=q.dtype, interpret=interpret)
+    return run(kv_len.astype(jnp.int32), tables.astype(jnp.int32),
+               q.reshape(B, KVH, group, D), pool_k.reshape(P, ps, KVH * D),
+               pool_v.reshape(P, ps, KVH * D))

@@ -6,7 +6,13 @@ online-softmax running state (acc, m, l) lives in VMEM scratch and is carried
 across K blocks. Block sizes default to 128 (MXU-aligned); q/k/v tiles are
 streamed HBM->VMEM by BlockSpecs.
 
-Layouts: q (B, Sq, H, D); k, v (B, Sk, KVH, D); out (B, Sq, H, D).
+Layouts: q (B, Sq, H, D); k, v (B, Sk, KVH, D); out (B, Sq, H, D). The
+wrapper views them lane-folded, (B, S, H*D) — a free reshape — so every tile
+is a (block, D) slab picked by the head index along the last axis. Mosaic
+needs a tile's last two dims divisible by (8, 128) or equal to the array's,
+so compiled runs need ``D % 128 == 0``; interpret mode takes any D. Sequence
+lengths that are not a block multiple are zero-padded up to one, and padded
+keys are masked out.
 """
 from __future__ import annotations
 
@@ -20,6 +26,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # TPU vector lane width; m/l scratch is (block_q, LANES)
+
+
+def check_lane_width(d: int, interpret: bool) -> None:
+    """Compiled kernels tile the lane-folded (…, heads*d) view in d-wide
+    slabs; Mosaic only accepts those when d is a multiple of 128."""
+    if not interpret and d % LANES:
+        raise ValueError(
+            f"compiled Pallas attention needs head_dim % {LANES} == 0 (got "
+            f"{d}); run such models with REPRO_KERNEL_MODE=ref")
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _kernel(
@@ -38,6 +57,7 @@ def _kernel(
     block_k: int,
     num_k_blocks: int,
     q_offset: int,
+    kv_len: Optional[int],
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -61,9 +81,9 @@ def _kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :]  # (block_q, D)
-        k = k_ref[0, :, 0, :]  # (block_k, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[...]  # (block_q, D)
+        k = k_ref[...]  # (block_k, D)
+        v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -75,6 +95,8 @@ def _kernel(
             mask &= kpos <= qpos
         if window is not None:
             mask &= kpos > qpos - window
+        if kv_len is not None:
+            mask &= kpos < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, :1]
@@ -96,7 +118,7 @@ def _kernel(
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-20)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -115,12 +137,18 @@ def flash_attention(
     B, Sq, H, D = q.shape
     _, Sk, KVH, _ = k.shape
     assert H % KVH == 0
+    check_lane_width(D, interpret)
     group = H // KVH
     scale = scale if scale is not None else 1.0 / (D**0.5)
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
-    assert Sq % block_q == 0 and Sk % block_k == 0
-    nq, nk = Sq // block_q, Sk // block_k
+    sq_p, sk_p = _round_up(Sq, block_q), _round_up(Sk, block_k)
+    nq, nk = sq_p // block_q, sk_p // block_k
+
+    # lane-folded views (free reshapes), zero-padded to whole blocks
+    qf = jnp.pad(q.reshape(B, Sq, H * D), ((0, 0), (0, sq_p - Sq), (0, 0)))
+    kf = jnp.pad(k.reshape(B, Sk, KVH * D), ((0, 0), (0, sk_p - Sk), (0, 0)))
+    vf = jnp.pad(v.reshape(B, Sk, KVH * D), ((0, 0), (0, sk_p - Sk), (0, 0)))
 
     kernel = functools.partial(
         _kernel,
@@ -131,25 +159,22 @@ def flash_attention(
         block_k=block_k,
         num_k_blocks=nk,
         q_offset=q_offset,
+        kv_len=Sk if sk_p != Sk else None,
     )
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, block_q, D), lambda b, h, qi, ki: (b, qi, h))
+    kv_spec = pl.BlockSpec(
+        (None, block_k, D), lambda b, h, qi, ki: (b, ki, h // group))
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // group, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // group, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qf, kf, vf)
+    return out[:, :Sq].reshape(B, Sq, H, D)

@@ -6,14 +6,28 @@ Mode resolution (``REPRO_KERNEL_MODE`` env var or :func:`set_mode`):
   pallas    -> force compiled Pallas
   interpret -> Pallas with interpret=True (kernel-correctness tests on CPU)
   ref       -> force pure-jnp oracles
+
+Backward passes: ``flash_attention``, ``rmsnorm`` and ``ssd`` are
+differentiable in every mode. In ``pallas`` and ``interpret`` mode the
+forward is the Pallas kernel and the backward is ``jax.vjp`` of the matching
+``kernels/ref.py`` oracle, recomputed from the saved inputs — it runs as
+plain XLA (no Pallas backward kernel exists yet), under a
+``jax.named_scope`` named ``<op>_bwd`` so profiles and HLO show it.
+
+Meshes: GSPMD cannot partition a Mosaic kernel, so under an ambient mesh of
+several devices every Pallas call runs through ``jax.shard_map``, one batch
+shard per data-parallel group (:func:`_batch_parallel`).
 """
 from __future__ import annotations
 
+import functools
+import math
 import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
@@ -21,6 +35,7 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import sampling as _sm
 from repro.kernels import ssd as _ssd
 from repro.kernels import rmsnorm as _rn
+from repro.utils.jax_compat import ambient_mesh, shard_map
 
 _MODE: Optional[str] = None
 
@@ -38,24 +53,65 @@ def current_mode() -> str:
     return mode
 
 
+def _batch_parallel(kernel, batched):
+    """``kernel`` run per data shard of the ambient mesh.
+
+    ``batched`` flags, per positional argument, whether axis 0 is the batch
+    (split over every mesh axis but ``model``) or the argument is replicated
+    (weights, the page pool). Every output carries the batch on axis 0. A
+    batch the data axes do not divide runs whole on every device. Without a
+    mesh of several devices the kernel is returned as it is."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel
+    axes = tuple(a for a in mesh.axis_names if a != "model")
+
+    def run(*args):
+        batch = args[batched.index(True)].shape[0]
+        spec = P(axes) if batch % math.prod(mesh.shape[a] for a in axes) == 0 \
+            else P()
+        return shard_map(
+            kernel, mesh=mesh, out_specs=spec, check_vma=False,
+            in_specs=tuple(spec if b else P() for b in batched))(*args)
+
+    return run
+
+
+def _with_ref_vjp(name, kernel, reference):
+    """``kernel`` as the forward, ``jax.vjp(reference)`` recomputed from the
+    saved inputs as the backward. Both take the same positional arrays and
+    agree in value; static options are bound by the caller."""
+
+    @jax.custom_vjp
+    def op(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        with jax.named_scope(f"{name}_bwd"):
+            _, vjp = jax.vjp(reference, *args)
+            return vjp(g)
+
+    op.defvjp(fwd, bwd)
+    return op
+
+
 def flash_attention(
     q, k, v, *, causal=True, window=None, scale=None, q_offset=0
 ):
+    """Differentiable in every mode; the Pallas modes take their backward
+    from the reference (see the module docstring)."""
     mode = current_mode()
+    opts = dict(causal=causal, window=window, scale=scale, q_offset=q_offset)
+    reference = functools.partial(_ref.flash_attention, **opts)
     if mode == "ref":
-        return _ref.flash_attention(
-            q, k, v, causal=causal, window=window, scale=scale, q_offset=q_offset
-        )
-    return _fa.flash_attention(
-        q,
-        k,
-        v,
-        causal=causal,
-        window=window,
-        scale=scale,
-        q_offset=q_offset,
-        interpret=(mode == "interpret"),
-    )
+        return reference(q, k, v)
+    kernel = _batch_parallel(functools.partial(
+        _fa.flash_attention, interpret=(mode == "interpret"), **opts),
+        (True, True, True))
+    return _with_ref_vjp("flash_attention", kernel, reference)(q, k, v)
 
 
 def decode_attention(
@@ -74,16 +130,10 @@ def decode_attention(
             pos_offset=pos_offset,
             return_lse=True,
         )
-    return _da.decode_attention(
-        q,
-        k,
-        v,
-        cache_len,
-        scale=scale,
-        window=window,
-        pos_offset=pos_offset,
-        interpret=(mode == "interpret"),
-    )
+    kernel = functools.partial(
+        _da.decode_attention, scale=scale, window=window,
+        pos_offset=pos_offset, interpret=(mode == "interpret"))
+    return _batch_parallel(kernel, (True,) * 4)(q, k, v, cache_len)
 
 
 def decode_attention_quant(
@@ -100,11 +150,11 @@ def decode_attention_quant(
             scale=scale, window=window, pos_offset=pos_offset,
             return_lse=True,
         )
-    return _da.decode_attention_quant(
-        q, k, v, k_scale, v_scale, cache_len,
-        scale=scale, window=window, pos_offset=pos_offset,
-        interpret=(mode == "interpret"),
-    )
+    kernel = functools.partial(
+        _da.decode_attention_quant, scale=scale, window=window,
+        pos_offset=pos_offset, interpret=(mode == "interpret"))
+    return _batch_parallel(kernel, (True,) * 6)(
+        q, k, v, k_scale, v_scale, cache_len)
 
 
 def paged_decode_attention(
@@ -119,10 +169,11 @@ def paged_decode_attention(
         return _ref.paged_decode_attention(
             q, pool_k, pool_v, tables, kv_len, scale=scale, return_lse=True
         )
-    return _da.paged_decode_attention(
-        q, pool_k, pool_v, tables, kv_len, scale=scale,
-        interpret=(mode == "interpret"),
-    )
+    kernel = functools.partial(
+        _da.paged_decode_attention, scale=scale,
+        interpret=(mode == "interpret"))
+    return _batch_parallel(kernel, (True, False, False, True, True))(
+        q, pool_k, pool_v, tables, kv_len)
 
 
 def _row_seeds(keys: jax.Array) -> jax.Array:
@@ -156,10 +207,7 @@ def fused_sample(
     inv_t = jnp.full(
         (B,), 0.0 if temperature == 0.0 else 1.0 / temperature, jnp.float32
     )
-    return _sm.fused_sample(
-        h, w_head, seeds, inv_t, vocab_size=vocab_size,
-        interpret=(mode == "interpret"),
-    )
+    return _sampler(vocab_size, mode)(h, w_head, seeds, inv_t)
 
 
 def fused_sample_rows(h, w_head, keys, temps, *, vocab_size=None) -> jax.Array:
@@ -175,11 +223,14 @@ def fused_sample_rows(h, w_head, keys, temps, *, vocab_size=None) -> jax.Array:
     inv_t = jnp.where(
         temps <= 0.0, 0.0, 1.0 / jnp.maximum(temps, 1e-6)
     ).astype(jnp.float32)
-    tok, _ = _sm.fused_sample(
-        h, w_head, seeds, inv_t, vocab_size=vocab_size,
-        interpret=(mode == "interpret"),
-    )
+    tok, _ = _sampler(vocab_size, mode)(h, w_head, seeds, inv_t)
     return tok
+
+
+def _sampler(vocab_size, mode):
+    kernel = functools.partial(_sm.fused_sample, vocab_size=vocab_size,
+                               interpret=(mode == "interpret"))
+    return _batch_parallel(kernel, (True, False, True, True))
 
 
 def combine_decode_shards(o_parts, lse_parts):
@@ -187,14 +238,18 @@ def combine_decode_shards(o_parts, lse_parts):
 
 
 def ssd(x, dt, A, Bm, Cm, D, *, chunk=128, return_state=False):
+    """Differentiable in every mode; the Pallas modes take their backward
+    from the chunked reference (see the module docstring)."""
     mode = current_mode()
+    reference = functools.partial(
+        _ref.ssd_chunked, chunk=min(chunk, x.shape[1]), return_state=True)
     if mode == "ref":
-        out = _ref.ssd_chunked(
-            x, dt, A, Bm, Cm, D, chunk=min(chunk, x.shape[1]), return_state=True
-        )
-        y, h = out
+        y, h = reference(x, dt, A, Bm, Cm, D)
     else:
-        y, h = _ssd.ssd(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=(mode == "interpret"))
+        kernel = _batch_parallel(functools.partial(
+            _ssd.ssd, chunk=chunk, interpret=(mode == "interpret")),
+            (True, True, False, True, True, False))
+        y, h = _with_ref_vjp("ssd", kernel, reference)(x, dt, A, Bm, Cm, D)
     if return_state:
         return y, h
     return y
@@ -206,7 +261,14 @@ def ssd_decode_step(x, dt, A, Bm, Cm, D, h):
 
 
 def rmsnorm(x, w, *, eps: float = 1e-6):
+    """Differentiable in every mode; the Pallas modes take their backward
+    from the reference (see the module docstring)."""
     mode = current_mode()
+    reference = functools.partial(_ref.rmsnorm, eps=eps)
     if mode == "ref":
-        return _ref.rmsnorm(x, w, eps=eps)
-    return _rn.rmsnorm(x, w, eps=eps, interpret=(mode == "interpret"))
+        return reference(x, w)
+    kernel = functools.partial(
+        _rn.rmsnorm, eps=eps, interpret=(mode == "interpret"))
+    if x.ndim > 1:
+        kernel = _batch_parallel(kernel, (True, False))
+    return _with_ref_vjp("rmsnorm", kernel, reference)(x, w)

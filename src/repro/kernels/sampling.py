@@ -20,9 +20,8 @@ stream but not bitwise-identical to it (that contract lives in
 
 ``inv_temp`` is per row with 0.0 meaning greedy (argmax of the raw logits) —
 one kernel serves both the rollout engine (one shared temperature) and the
-serving engine (per-request temperatures). Per-row seeds arrive through the
-scalar-prefetch lane as int32; inv_temp travels as f32 bits in int32 (SMEM's
-blessed dtype) and is bitcast back in-kernel.
+serving engine (per-request temperatures). Per-row seeds (int32) and inverse
+temperatures (f32) arrive as (B, 1) column tiles beside the hidden rows.
 """
 from __future__ import annotations
 
@@ -56,12 +55,15 @@ def _uniform_01(seed: jax.Array, pos: jax.Array) -> jax.Array:
     are always finite."""
     mixed = pos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1) + seed
     bits = _hash_u32(mixed) >> jnp.uint32(8)
+    # 24-bit values are exact in int32, and signed int->float is what
+    # every backend converts natively
+    bits = jax.lax.bitcast_convert_type(bits, jnp.int32)
     return (bits.astype(jnp.float32) + 0.5) * jnp.float32(1.0 / (1 << 24))
 
 
 def _sample_kernel(
-    seed_ref,  # scalar prefetch (B,) int32 per-row hash seeds
-    it_ref,  # scalar prefetch (B,) int32: f32 inv-temperature bits (0=greedy)
+    seed_ref,  # (block_b, 1) int32 per-row hash seeds
+    it_ref,  # (block_b, 1) f32 inverse temperature (0 = greedy)
     h_ref,
     w_ref,
     tok_ref,
@@ -76,7 +78,6 @@ def _sample_kernel(
     num_v_blocks: int,
     vocab_size: int,
 ):
-    b = pl.program_id(0)
     vi = pl.program_id(1)
 
     @pl.when(vi == 0)
@@ -90,8 +91,8 @@ def _sample_kernel(
     z = jax.lax.dot_general(
         h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # (1, block_v) untempered logits tile
-    pos = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, (1, block_v), 1)
+    )  # (block_b, block_v) untempered logits tile
+    pos = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
     pv = pos < vocab_size
     z = jnp.where(pv, z, NEG_INF)
 
@@ -106,8 +107,8 @@ def _sample_kernel(
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     # Gumbel-max score (greedy rows score the raw logits)
-    inv_temp = jax.lax.bitcast_convert_type(it_ref[b], jnp.float32)
-    seed = seed_ref[b].astype(jnp.uint32)
+    inv_temp = it_ref[...]
+    seed = jax.lax.bitcast_convert_type(seed_ref[...], jnp.uint32)
     u = _uniform_01(seed, pos)
     g = -jnp.log(-jnp.log(u))
     y = jnp.where(inv_temp == 0.0, z, z * inv_temp + g)
@@ -131,9 +132,14 @@ def _sample_kernel(
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-20)
         lse = m_ref[:, :1] + jnp.log(l)
-        tok_ref[0, :] = jnp.broadcast_to(bi_ref[:, :1], (1, LANES))[0]
-        lp_ref[0, :] = jnp.broadcast_to(
-            bz_ref[:, :1] - lse, (1, LANES))[0]
+        tok_ref[...] = bi_ref[...]
+        lp_ref[...] = jnp.broadcast_to(bz_ref[:, :1] - lse, lp_ref.shape)
+
+
+def _pick_block_b(B: int, want: int = 256) -> int:
+    """Rows per grid step: the whole batch when it fits, else the largest
+    multiple-of-8 divisor of B that does (a legal sublane tile either way)."""
+    return B if B <= want else _pick_block_s(B, want, 8)
 
 
 def fused_sample(
@@ -148,43 +154,48 @@ def fused_sample(
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused head+sampler. Returns (token (B,) int32, logprob (B,) f32 of the
     sampled token under the *untempered* masked distribution — the
-    behaviour-logprob contract of ``rl/rollout.generate``)."""
+    behaviour-logprob contract of ``rl/rollout.generate``).
+
+    Grid (B / block_b, Vp / block_v) with the vocab axis minor: each step
+    multiplies a (block_b, d) row block by one (d, block_v) head tile, so
+    the head weights stream once per row block — once per decode step when
+    the whole batch is one block. ``block_v`` is a multiple of 128 whenever
+    the padded vocab is (a whole-vocab tile otherwise)."""
     B, d = h.shape
     Vp = w_head.shape[1]
     vocab = Vp if vocab_size is None else vocab_size
-    block_v = _pick_block_s(Vp, block_v)
+    block_v = _pick_block_s(Vp, block_v, LANES)
     nv = Vp // block_v
+    block_b = _pick_block_b(B)
 
     kernel = functools.partial(
         _sample_kernel, block_v=block_v, num_v_blocks=nv, vocab_size=vocab)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nv),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b, vi, seeds, its: (b, 0)),
-            pl.BlockSpec((d, block_v), lambda b, vi, seeds, its: (0, vi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, LANES), lambda b, vi, seeds, its: (b, 0)),
-            pl.BlockSpec((1, LANES), lambda b, vi, seeds, its: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, LANES), jnp.float32),  # m
-            pltpu.VMEM((1, LANES), jnp.float32),  # l
-            pltpu.VMEM((1, LANES), jnp.float32),  # best gumbel score
-            pltpu.VMEM((1, LANES), jnp.float32),  # best untempered logit
-            pltpu.VMEM((1, LANES), jnp.int32),  # best index
-        ],
-    )
-    it_bits = jax.lax.bitcast_convert_type(
-        inv_temp.astype(jnp.float32), jnp.int32)
+    row = lambda b, vi: (b, 0)
     tok, lp = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(B // block_b, nv),
+        in_specs=[
+            pl.BlockSpec((block_b, 1), row),
+            pl.BlockSpec((block_b, 1), row),
+            pl.BlockSpec((block_b, d), row),
+            pl.BlockSpec((d, block_v), lambda b, vi: (0, vi)),
+        ],
+        out_specs=[
+            pl.BlockSpec((block_b, LANES), row),
+            pl.BlockSpec((block_b, LANES), row),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((B, LANES), jnp.int32),
             jax.ShapeDtypeStruct((B, LANES), jnp.float32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((block_b, LANES), jnp.float32),  # m
+            pltpu.VMEM((block_b, LANES), jnp.float32),  # l
+            pltpu.VMEM((block_b, LANES), jnp.float32),  # best gumbel score
+            pltpu.VMEM((block_b, LANES), jnp.float32),  # best untempered logit
+            pltpu.VMEM((block_b, LANES), jnp.int32),  # best index
+        ],
         interpret=interpret,
-    )(seeds.astype(jnp.int32), it_bits, h, w_head)
+    )(seeds.astype(jnp.int32)[:, None], inv_temp.astype(jnp.float32)[:, None],
+      h, w_head)
     return tok[:, 0], lp[:, 0]

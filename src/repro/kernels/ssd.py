@@ -15,14 +15,18 @@ Per chunk of length L (default 128):
   h       <- exp(tot_a) h + x^T @ (B * dt * exp(tot_a - cum_a))   (P,N) MXU
   y        = y_intra + y_inter + D * x
 
-Layouts: x (B,S,NH,P); dt (B,S,NH); A,D (NH,); Bm,Cm (B,S,G,N).
-State dim N and head dim P are zero-padded to the 128-lane boundary by the
-wrapper when needed.
+Layouts: x (B,S,NH,P); dt (B,S,NH); A,D (NH,); Bm,Cm (B,S,G,N). The
+wrapper moves heads ahead of the sequence, x (B,NH,S,P) and Bm/Cm (B,G,S,N),
+so each tile is a whole (chunk, P) / (chunk, N) slab — Mosaic needs a tile's
+last two dims divisible by (8, 128) or equal to the array's, and P = 64 is
+common. dt arrives twice, as a (chunk, 1) column and a (1, chunk) row, so the
+in-chunk prefix sums are masked reductions instead of a cumsum or an
+in-kernel transpose. A and D are per-head scalars in SMEM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,12 +35,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(
-    x_ref,
-    dt_ref,
-    a_ref,  # A (1,) for this head
-    b_ref,
-    c_ref,
-    d_ref,  # D (1,)
+    a_ref,  # scalar prefetch (NH,) f32: A
+    d_ref,  # scalar prefetch (NH,) f32: D
+    x_ref,  # (L, P)
+    dtc_ref,  # (L, 1)
+    dtr_ref,  # (1, L)
+    b_ref,  # (L, N)
+    c_ref,  # (L, N)
     y_ref,
     hout_ref,
     h_ref,  # scratch (P, N) fp32
@@ -44,57 +49,63 @@ def _kernel(
     chunk: int,
     num_chunks: int,
 ):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (L, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (L,)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
-    A = a_ref[0]
-    D = d_ref[0]
+    A = a_ref[hi]
+    D = d_ref[hi]
+    x = x_ref[...].astype(jnp.float32)  # (L, P)
+    dt_col = dtc_ref[...].astype(jnp.float32)  # (L, 1)
+    dt_row = dtr_ref[...].astype(jnp.float32)  # (1, L)
+    Bm = b_ref[...].astype(jnp.float32)  # (L, N)
+    Cm = c_ref[...].astype(jnp.float32)  # (L, N)
 
-    a = dt * A  # (L,)
-    a_cum = jnp.cumsum(a)  # inclusive
-    a_tot = a_cum[-1]
-
-    # intra-chunk
-    seg = a_cum[:, None] - a_cum[None, :]  # sum_{j<k<=i}
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lmat = jnp.where(li >= lj, jnp.exp(seg), 0.0)
+    lower = li >= lj
+    # inclusive prefix sums of the log-decay a = dt * A, as a column and a row
+    a_cum_col = jnp.sum(jnp.where(lower, dt_row * A, 0.0), axis=1,
+                        keepdims=True)  # (L, 1)
+    a_cum_row = jnp.sum(jnp.where(li <= lj, dt_col * A, 0.0), axis=0,
+                        keepdims=True)  # (1, L)
+    a_tot = jnp.sum(dt_row * A, axis=1, keepdims=True)  # (1, 1)
+
+    # intra-chunk
+    seg = a_cum_col - a_cum_row  # sum_{j<k<=i}
+    Lmat = jnp.where(lower, jnp.exp(seg), 0.0)
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     gated = scores * Lmat
     y_intra = jax.lax.dot_general(
-        gated, dt[:, None] * x, (((1,), (0,)), ((), ())),
+        gated, dt_col * x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     # inter-chunk: contribution of incoming state
     h = h_ref[...]  # (P, N)
-    c_dec = Cm * jnp.exp(a_cum)[:, None]  # (L, N)
+    c_dec = Cm * jnp.exp(a_cum_col)  # (L, N)
     y_inter = jax.lax.dot_general(
         c_dec, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (L, P)
 
     # state update
-    w = (dt * jnp.exp(a_tot - a_cum))[:, None] * Bm  # (L, N)
+    w = (dt_col * jnp.exp(a_tot - a_cum_col)) * Bm  # (L, N)
     s_new = jax.lax.dot_general(
         x, w, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (P, N)
     h_ref[...] = jnp.exp(a_tot) * h + s_new
 
     y = y_intra + y_inter + D * x
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     @pl.when(ci == num_chunks - 1)
     def _emit_state():
-        hout_ref[0, 0, :, :] = h_ref[...]
+        hout_ref[...] = h_ref[...]
 
 
 def ssd(
@@ -117,27 +128,40 @@ def ssd(
     assert s % chunk == 0
     nc = s // chunk
 
+    xh = jnp.swapaxes(x, 1, 2)  # (B, NH, S, P)
+    dth = jnp.swapaxes(dt, 1, 2)  # (B, NH, S)
     kernel = functools.partial(_kernel, chunk=chunk, num_chunks=nc)
-    y, h = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(b, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi // rep, 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi // rep, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda bi, hi, ci, *_: (bi, hi, ci, 0)),
+            pl.BlockSpec((None, None, chunk, 1),
+                         lambda bi, hi, ci, *_: (bi, hi, ci, 0)),
+            pl.BlockSpec((None, None, 1, chunk),
+                         lambda bi, hi, ci, *_: (bi, hi, 0, ci)),
+            pl.BlockSpec((None, None, chunk, n),
+                         lambda bi, hi, ci, *_: (bi, hi // rep, ci, 0)),
+            pl.BlockSpec((None, None, chunk, n),
+                         lambda bi, hi, ci, *_: (bi, hi // rep, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, nh, p), x.dtype),
-            jax.ShapeDtypeStruct((b, nh, p, n), jnp.float32),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda bi, hi, ci, *_: (bi, hi, ci, 0)),
+            pl.BlockSpec((None, None, p, n),
+                         lambda bi, hi, ci, *_: (bi, hi, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+    )
+    y, h = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, nh, s, p), x.dtype),
+            jax.ShapeDtypeStruct((b, nh, p, n), jnp.float32),
+        ],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm, D)
-    return y, h
+    )(A.astype(jnp.float32), D.astype(jnp.float32), xh, dth[..., None],
+      dth[:, :, None, :], jnp.swapaxes(Bm, 1, 2), jnp.swapaxes(Cm, 1, 2))
+    return jnp.swapaxes(y, 1, 2), h

@@ -6,9 +6,8 @@ Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model) — `pod` is the
 extra data-parallel dimension whose gradient reduction crosses the
 inter-pod links.
 
-``make_compat_mesh`` is the version-tolerant constructor every caller should
-use: newer jax releases want explicit ``axis_types=(AxisType.Auto, ...)``,
-older ones (<= 0.4.x) have neither the kwarg nor ``jax.sharding.AxisType``.
+Every mesh is built by ``repro.utils.jax_compat.make_compat_mesh``, which
+gives each axis ``AxisType.Auto``.
 
 Fleet bring-up (docs/multihost.md): :func:`init_distributed` resolves the
 ``coordinator`` string — ``host:port`` means real multi-process jax
@@ -26,7 +25,7 @@ from typing import Optional
 
 import jax
 
-from repro.utils.jax_compat import auto_axis_types, make_compat_mesh, use_mesh
+from repro.utils.jax_compat import make_compat_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -107,12 +106,5 @@ def make_fleet_mesh(num_hosts: int, devices_per_host: int = 0,
             f"devices_per_host {devices_per_host} not divisible by "
             f"model_parallel {model_parallel}")
     shape = (num_hosts, devices_per_host // model_parallel, model_parallel)
-    axes = ("pod", "data", "model")
-    types = auto_axis_types(len(axes))
-    if types is not None:
-        try:
-            return jax.make_mesh(shape, axes, devices=devices[:need],
-                                 axis_types=types)
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return make_compat_mesh(shape, ("pod", "data", "model"),
+                            devices=devices[:need])

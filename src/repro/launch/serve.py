@@ -27,6 +27,7 @@ from repro.data.tokenizer import ByteTokenizer
 from repro.launch.mesh import make_local_mesh
 from repro.models import get_model
 from repro.rl.rollout import generate
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.jax_compat import use_mesh
 
 
@@ -72,8 +73,10 @@ def run_lockstep(model, params, tok, args) -> None:
           f"p99 {p['p99'] * 1e3:.1f}ms)")
 
 
-def run_streaming(model, params, args) -> None:
-    """Request-streaming serving over a synthetic Poisson arrival stream."""
+def run_streaming(model, params, args):
+    """Request-streaming serving over a synthetic Poisson arrival stream.
+    Returns the finished streams; exits non-zero if any stream is left
+    unfinished."""
     from repro.obs import MetricsRegistry
     from repro.serving import ServingEngine, synthetic_requests
 
@@ -112,12 +115,14 @@ def run_streaming(model, params, args) -> None:
           f"({int(st['prefix_hit_tokens'])} of {int(st['prompt_tokens'])} "
           f"prompt tokens), occupancy {st['slot_occupancy']:.0%}, "
           f"parks {int(st['parks'])}, pool pages {int(st['pool_pages_used'])}")
-    done = sum(s.finished for s in streams)
-    if done != len(streams):
-        print(f"[serve] WARNING: {len(streams) - done} streams unfinished")
+    unfinished = sum(not s.finished for s in streams)
+    if unfinished:
+        raise SystemExit(f"[serve] {unfinished} of {len(streams)} streams "
+                         "unfinished")
+    return streams
 
 
-def main(argv=None) -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--smoke", action="store_true")
@@ -143,12 +148,17 @@ def main(argv=None) -> None:
     ap.add_argument("--requests", type=int, default=3,
                     help="batches to serve (lockstep)")
     args = ap.parse_args(argv)
+    args.eos_id = ByteTokenizer().eos_id
+    return args
 
+
+def main(argv=None) -> None:
+    args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduced(cfg, vocab_size=260, num_layers=2)
     tok = ByteTokenizer()
-    args.eos_id = tok.eos_id
     model = get_model(cfg)
     mesh = make_local_mesh()
     with use_mesh(mesh):

@@ -36,7 +36,8 @@ from repro.ft import checkpoint
 from repro.launch.mesh import init_distributed, make_fleet_mesh, make_local_mesh
 from repro.rl import RLConfig, list_algorithms
 from repro.rl.trainer import TrainState
-from repro.utils.jax_compat import use_mesh
+from repro.utils.compile_cache import enable_compile_cache
+from repro.utils.jax_compat import make_compat_mesh, use_mesh
 
 
 def build_experiment(args) -> ExperimentSpec:
@@ -108,7 +109,10 @@ def build_experiment(args) -> ExperimentSpec:
     )
 
 
-def main(argv=None) -> None:
+def main(argv=None):
+    """Run the driver; returns ``(pipeline, per-iteration metrics)`` for
+    callers that drive it in-process (``chip_smoke.py``), or None after
+    ``--dump-experiment``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-7b")
     ap.add_argument("--algorithm", choices=list_algorithms(), default="grpo")
@@ -186,6 +190,7 @@ def main(argv=None) -> None:
             f.write(exp.to_json())
         print(f"[train] wrote {args.dump_experiment}")
         return
+    enable_compile_cache()
     cfg = exp.model
     dist = exp.distributed
     fleet_ctx = None
@@ -198,6 +203,8 @@ def main(argv=None) -> None:
         if fleet_ctx is not None:
             fleet_ctx.start_heartbeats()
             fleet_ctx.barrier("startup")
+    elif exp.mesh_shape:
+        mesh = make_compat_mesh(tuple(exp.mesh_shape), tuple(exp.mesh_axes))
     else:
         mesh = make_local_mesh()
 
@@ -221,12 +228,14 @@ def main(argv=None) -> None:
         jsonl_sink = (JSONLSink(obs_rt.cfg.metrics_path)
                       if obs_rt is not None and obs_rt.cfg.metrics_path
                       else None)
+        history = []
         for it in range(start, args.iters):
             if fleet_ctx is not None:
                 fleet_ctx.heartbeat(it)
             t0 = time.perf_counter()
             metrics = pipe.worker.run_iteration()
             dt = time.perf_counter() - t0
+            history.append(metrics)
             if it % 5 == 0 or it == args.iters - 1:
                 stdout_sink.emit_iteration(it, metrics, dt)
             if obs_rt is not None:
@@ -245,6 +254,7 @@ def main(argv=None) -> None:
             print(f"[train] wrote trace {obs_rt.cfg.trace_path} "
                   f"({obs_rt.tracer.num_events} events)")
         print(f"[train] done; buffer stats: {pipe.buffer.stats}")
+    return pipe, history
 
 
 if __name__ == "__main__":

@@ -1,90 +1,30 @@
-"""Version-tolerance shims for the jax APIs this repo uses.
+"""The mesh and sharding calls this repo makes, in one place.
 
-The codebase targets current jax (explicit ``AxisType.Auto`` meshes, the
-``jax.sharding.set_mesh`` ambient-mesh context, ``get_abstract_mesh``), but
-must also run on older 0.4.x releases where none of those exist. Every
-call site goes through these helpers instead of feature-testing jax inline:
+Written against the installed jax (0.9): meshes carry explicit
+``AxisType.Auto`` axes, the ambient mesh is ``jax.sharding.set_mesh``, and
+``jax.shard_map`` is the manual-partitioning entry point.
 
-* :func:`auto_axis_types` / :func:`make_compat_mesh` — mesh construction.
-* :func:`use_mesh` — ambient-mesh context manager: ``set_mesh`` when
-  available, else the legacy ``with mesh:`` context plus a module-local
-  stack so :func:`ambient_mesh` still answers.
+* :func:`make_compat_mesh` — ``jax.make_mesh`` with Auto axes.
+* :data:`use_mesh` — enter a mesh as the ambient mesh for sharding
+  constraints.
+* :data:`shard_map` — ``jax.shard_map``.
 * :func:`ambient_mesh` — the mesh model code should resolve logical axis
   names against, or None (-> sharding constraints no-op, keeping model code
-  mesh-agnostic exactly as before).
+  mesh-agnostic).
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Optional
-
 import jax
+from jax.sharding import AxisType
 
-_LEGACY_AMBIENT: list = []  # fallback ambient-mesh stack for pre-set_mesh jax
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n on jax versions that have AxisType, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else (axis_type.Auto,) * n
+use_mesh = jax.sharding.set_mesh
+shard_map = jax.shard_map
 
 
-def make_compat_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis_types when the running jax supports
-    them, plain otherwise — the two spell the same mesh."""
-    types = auto_axis_types(len(axes))
-    if types is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=types)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh for sharding constraints."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-        return
-    _LEGACY_AMBIENT.append(mesh)
-    try:
-        with mesh:  # legacy context: enables with_sharding_constraint(x, P)
-            yield mesh
-    finally:
-        _LEGACY_AMBIENT.pop()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` on current jax; ``jax.experimental.shard_map`` (with
-    its ``check_rep`` spelling of ``check_vma``) on older releases."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        try:
-            return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        except TypeError:
-            pass  # a jax with jax.shard_map but the old check_rep kwarg
-    from jax.experimental.shard_map import shard_map as legacy
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def peak_memory_bytes(stats) -> int:
-    """``CompiledMemoryStats.peak_memory_in_bytes`` where jaxlib provides it;
-    the temp+argument+output sum (the dominant contributors) on older
-    releases that only expose the per-category sizes."""
-    peak = getattr(stats, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    return int(
-        stats.temp_size_in_bytes
-        + stats.argument_size_in_bytes
-        + stats.output_size_in_bytes
-    )
+def make_compat_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def ambient_mesh():
@@ -94,13 +34,7 @@ def ambient_mesh():
     with_sharding_constraint only accepts Auto axes, so callers must no-op
     inside shard_map manual regions.
     """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is None:
-        return _LEGACY_AMBIENT[-1] if _LEGACY_AMBIENT else None
-    mesh = get_abstract()
-    if mesh.empty:
-        return None
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and any(t != axis_type.Auto for t in mesh.axis_types):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or any(t != AxisType.Auto for t in mesh.axis_types):
         return None
     return mesh

@@ -10,6 +10,10 @@ Each host is a fresh ``python tests/fleet/train_host.py`` subprocess with:
   count, gradient compression, and (optionally) an iteration at which to
   SIGKILL itself mid-run (elastic-recovery tests).
 
+Every host is forced onto the CPU (``JAX_PLATFORMS=cpu``) on purpose: this
+is a simulation of a multi-host fleet, never a chip run — on a TPU machine a
+child process could not reach the chip its parent holds.
+
 Artifacts are one JSON file per host (params digest, per-iteration metric
 history, membership/epoch view, exchange + buffer stats); tests assert the
 cross-host invariants on those.

@@ -491,3 +491,72 @@ def test_rmsnorm(shape, dtype):
     y_p = rn_pallas(x, w, block_rows=32, interpret=True)
     np.testing.assert_allclose(np.array(y_p, np.float32),
                                np.array(y_r, np.float32), **tol(dtype))
+
+
+# --------------------------------------------------------------------------- #
+# custom_vjp: the Pallas forward with the reference's backward
+# --------------------------------------------------------------------------- #
+@pytest.fixture
+def interpret_mode():
+    from repro.kernels import ops
+
+    ops.set_mode("interpret")
+    try:
+        yield ops
+    finally:
+        ops.set_mode(None)
+
+
+def _grads(fn, args):
+    """Gradient of a loss whose cotangent depends on the forward's value, so
+    the Pallas forward's output feeds the backward."""
+    def loss(*a):
+        out = fn(*a)
+        outs = out if isinstance(out, tuple) else (out,)
+        return sum(jnp.sum(jnp.sin(o.astype(jnp.float32))) for o in outs)
+
+    return jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+
+
+def _assert_grads_close(got, want):
+    # f32 inputs: the two forwards differ only by accumulation order (~1e-6
+    # relative), and the shared reference backward carries that through
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.array(g), np.array(w),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("H,KVH,window", [
+    (4, 4, None),   # causal MHA
+    (4, 4, 48),     # sliding window
+    (8, 2, None),   # GQA group 4
+])
+def test_flash_attention_grad_matches_ref(interpret_mode, H, KVH, window):
+    k = jax.random.split(jax.random.PRNGKey(20), 3)
+    B, S, D = 2, 128, 32
+    q = jax.random.normal(k[0], (B, S, H, D))
+    kk = jax.random.normal(k[1], (B, S, KVH, D))
+    vv = jax.random.normal(k[2], (B, S, KVH, D))
+    got = _grads(lambda *a: interpret_mode.flash_attention(
+        *a, causal=True, window=window), (q, kk, vv))
+    want = _grads(lambda *a: ref.flash_attention(
+        *a, causal=True, window=window), (q, kk, vv))
+    _assert_grads_close(got, want)
+
+
+def test_rmsnorm_grad_matches_ref(interpret_mode):
+    k = jax.random.split(jax.random.PRNGKey(21), 2)
+    x = jax.random.normal(k[0], (3, 40, 64))
+    w = jax.random.normal(k[1], (64,)) * 0.1
+    got = _grads(interpret_mode.rmsnorm, (x, w))
+    want = _grads(ref.rmsnorm, (x, w))
+    _assert_grads_close(got, want)
+
+
+def test_ssd_grad_matches_ref(interpret_mode):
+    args = _ssd_inputs(jax.random.PRNGKey(22), 2, 64, 4, 16, 2, 16)
+    got = _grads(lambda *a: interpret_mode.ssd(
+        *a, chunk=16, return_state=True), args)
+    want = _grads(lambda *a: ref.ssd_chunked(
+        *a, chunk=16, return_state=True), args)
+    _assert_grads_close(got, want)

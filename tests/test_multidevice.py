@@ -1,6 +1,8 @@
 """Multi-device behaviour tests. Each test runs a subprocess with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 so the main pytest process
-keeps seeing 1 device (per the dry-run isolation rule)."""
+keeps seeing 1 device (per the dry-run isolation rule). The children are
+CPU-forced on purpose: these are CPU simulations of a mesh, not chip runs
+(a child cannot reach a chip its parent holds)."""
 import json
 import os
 import subprocess
@@ -18,7 +20,7 @@ def run_py(body: str) -> str:
         "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
         "import sys\n"
         f"sys.path.insert(0, {os.path.join(REPO, 'src')!r})\n"
-        "from repro.utils.jax_compat import make_compat_mesh, use_mesh, shard_map, peak_memory_bytes\n"
+        "from repro.utils.jax_compat import make_compat_mesh, use_mesh, shard_map\n"
         + textwrap.dedent(body)
     )
     proc = subprocess.run(

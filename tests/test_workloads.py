@@ -17,7 +17,7 @@ def run_py(body: str) -> str:
         "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
         "import sys\n"
         f"sys.path.insert(0, {os.path.join(REPO, 'src')!r})\n"
-        "from repro.utils.jax_compat import make_compat_mesh, use_mesh, shard_map, peak_memory_bytes\n"
+        "from repro.utils.jax_compat import make_compat_mesh, use_mesh, shard_map\n"
         + textwrap.dedent(body)
     )
     proc = subprocess.run(
@@ -45,7 +45,7 @@ def test_workload_cells_compile_small_mesh(arch):
                 wl = build_workload(cfg, ShapeConfig('t', S, B, kind), mesh)
                 compiled = wl.fn.lower(*wl.args).compile()
                 mem = compiled.memory_analysis()
-                assert peak_memory_bytes(mem) > 0
+                assert mem.peak_memory_in_bytes > 0
         print('OK')
     """)
     assert "OK" in out
