@@ -72,11 +72,9 @@ def actor_generate(ctx, buffer, node: Node) -> Dict:
         # balancer exactly like every other per-sequence key)
         buffer.put("env_rewards", jnp.asarray(env_out["rewards"]), model_spec)
         buffer.put("env_turns", jnp.asarray(env_out["turns"]), model_spec)
-    gen_tokens = float(jnp.sum(res.lengths))
-    ctx.counters["gen_tokens"] = ctx.counters.get("gen_tokens", 0.0) + gen_tokens
     out = {
         "rollout/mean_len": float(jnp.mean(res.lengths.astype(jnp.float32))),
-        "rollout/tokens": gen_tokens,
+        "rollout/tokens": float(jnp.sum(res.lengths)),
     }
     stats = getattr(engine, "last_stats", None)
     if stats:  # continuous engine: slot/throughput accounting

@@ -11,7 +11,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -54,7 +54,6 @@ class WorkerContext:
     # the ObsState (repro.obs) when an ObsConfig is enabled; None = no
     # telemetry, the zero-overhead default
     obs: Any = None
-    counters: Dict[str, float] = field(default_factory=dict)
 
     def next_key(self):
         self.key, sub = jax.random.split(self.key)
@@ -76,12 +75,14 @@ class PromptSource:
         self.group_size = group_size
 
     def next_prompts(self):
-        batch = self.dataloader.next_batch()
-        prompts, answers = batch["prompts"], batch["answers"]
-        if self.group_size > 1:
-            prompts = jnp.repeat(prompts, self.group_size, axis=0)
-            answers = jnp.repeat(answers, self.group_size, axis=0)
-        return prompts, answers
+        with get_tracer().span("prompts/next", cat="dag",
+                               group=self.group_size):
+            batch = self.dataloader.next_batch()
+            prompts, answers = batch["prompts"], batch["answers"]
+            if self.group_size > 1:
+                prompts = jnp.repeat(prompts, self.group_size, axis=0)
+                answers = jnp.repeat(answers, self.group_size, axis=0)
+            return prompts, answers
 
 
 class DAGWorker:
@@ -180,6 +181,10 @@ class DAGWorker:
         nb = self._num_buckets()
         if nb <= 1 or "response_mask" not in self.buffer.keys():
             return {}
+        with get_tracer().span("worker/balance", cat="dag", buckets=nb):
+            return self._repack_by_length(nb)
+
+    def _repack_by_length(self, nb: int) -> Dict[str, float]:
         skipped = {"balance/skipped": 1.0}
         from repro.rl import algorithms
 

@@ -323,33 +323,39 @@ def _apply_block(
     write_enable: Optional[jax.Array] = None,
 ):
     mixer_kind, mlp_kind = kind
+    # each sub-layer's residual add sits in its scope, so a matmul fused
+    # with the add keeps the sub-layer's name in the device trace
     hn = layers.apply_norm(cfg, p["norm1"], h)
-    if mixer_kind == "attn":
-        mix_out, new_cache = _attn_mixer(
-            cfg, p["attn"], hn, positions, mode, cache, cache_len, smax,
-            chunk_offset, page_tables, write_enable)
-    else:
-        assert page_tables is None, "paged decode: attention-only archs"
-        mix_out, new_cache = _ssm_mixer(cfg, p["ssm"], hn, mode, cache)
+    with jax.named_scope("mixer"):
+        if mixer_kind == "attn":
+            mix_out, new_cache = _attn_mixer(
+                cfg, p["attn"], hn, positions, mode, cache, cache_len, smax,
+                chunk_offset, page_tables, write_enable)
+        else:
+            assert page_tables is None, "paged decode: attention-only archs"
+            mix_out, new_cache = _ssm_mixer(cfg, p["ssm"], hn, mode, cache)
+        if not cfg.parallel_block:
+            h = h + mix_out
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.parallel_block:
-        if mlp_kind == "dense":
-            mlp_out = layers.apply_mlp(cfg, p["mlp"], hn)
-        elif mlp_kind == "moe":
-            mlp_out, aux = moe.apply_moe(cfg, p["moe"], hn)
-        else:
-            mlp_out = 0.0
-        return h + mix_out + mlp_out, aux, new_cache
+        with jax.named_scope("mlp"):
+            if mlp_kind == "dense":
+                mlp_out = layers.apply_mlp(cfg, p["mlp"], hn)
+            elif mlp_kind == "moe":
+                mlp_out, aux = moe.apply_moe(cfg, p["moe"], hn)
+            else:
+                mlp_out = 0.0
+            return h + mix_out + mlp_out, aux, new_cache
 
-    h = h + mix_out
     if mlp_kind != "none":
         hn2 = layers.apply_norm(cfg, p["norm2"], h)
-        if mlp_kind == "dense":
-            h = h + layers.apply_mlp(cfg, p["mlp"], hn2)
-        else:
-            mlp_out, aux = moe.apply_moe(cfg, p["moe"], hn2)
-            h = h + mlp_out
+        with jax.named_scope("mlp"):
+            if mlp_kind == "dense":
+                h = h + layers.apply_mlp(cfg, p["mlp"], hn2)
+            else:
+                mlp_out, aux = moe.apply_moe(cfg, p["moe"], hn2)
+                h = h + mlp_out
     return h, aux, new_cache
 
 
@@ -438,6 +444,14 @@ def _head_matrix(cfg: ModelConfig, params: Params) -> jax.Array:
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
 
+def _head_logits(cfg: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
+    """(B, d) last hidden states -> (B, V) float32 logits, padded vocabulary
+    slots masked."""
+    with jax.named_scope("head"):
+        logits = (h @ _head_matrix(cfg, params)).astype(jnp.float32)
+        return mask_padded_vocab(cfg, logits)
+
+
 def assemble_input(
     cfg: ModelConfig, params: Params, tokens: jax.Array,
     prefix_embeds: Optional[jax.Array],
@@ -497,10 +511,11 @@ def _chunked_head_scan(h, w_head, labels, chunk, vocab_size=None, unroll=False):
 
 
 def token_stats(cfg, params, h, labels, chunk=LOSS_CHUNK, unroll=False):
-    return _chunked_head_scan(
-        h, _head_matrix(cfg, params), labels, chunk, vocab_size=cfg.vocab_size,
-        unroll=unroll,
-    )
+    with jax.named_scope("head"):
+        return _chunked_head_scan(
+            h, _head_matrix(cfg, params), labels, chunk,
+            vocab_size=cfg.vocab_size, unroll=unroll,
+        )
 
 
 def ce_loss(cfg, params, h, labels, unroll=False):
@@ -613,8 +628,7 @@ def prefill(
     h, _, caches = backbone(
         cfg, params, h, positions, mode="prefill", smax=smax, unroll=unroll
     )
-    logits = (h[:, -1] @ _head_matrix(cfg, params)).astype(jnp.float32)
-    logits = mask_padded_vocab(cfg, logits)
+    logits = _head_logits(cfg, params, h[:, -1])
     cache_len = jnp.full((tokens.shape[0],), h.shape[1], jnp.int32)
     return logits, caches, cache_len
 
@@ -654,9 +668,7 @@ def decode_step(
     """One decode step. Returns (logits (B,V), new_caches, cache_len+1)."""
     h, new_caches = _decode_hidden(
         cfg, params, token, caches, cache_len, unroll=unroll)
-    logits = (h @ _head_matrix(cfg, params)).astype(jnp.float32)
-    logits = mask_padded_vocab(cfg, logits)
-    return logits, new_caches, cache_len + 1
+    return _head_logits(cfg, params, h), new_caches, cache_len + 1
 
 
 def decode_step_sample(
@@ -679,10 +691,11 @@ def decode_step_sample(
     ``log_softmax`` gather."""
     h, new_caches = _decode_hidden(
         cfg, params, token, caches, cache_len, unroll=unroll)
-    tok, lp = ops.fused_sample(
-        h, _head_matrix(cfg, params), key, temperature,
-        vocab_size=cfg.vocab_size, top_p=top_p,
-    )
+    with jax.named_scope("head"):
+        tok, lp = ops.fused_sample(
+            h, _head_matrix(cfg, params), key, temperature,
+            vocab_size=cfg.vocab_size, top_p=top_p,
+        )
     return tok, lp, new_caches, cache_len + 1
 
 
@@ -702,8 +715,7 @@ def decode_step_paged(
     h, new_pool = _decode_hidden(
         cfg, params, token, pool, cache_len, unroll=unroll,
         page_tables=page_tables, write_enable=write_enable)
-    logits = (h @ _head_matrix(cfg, params)).astype(jnp.float32)
-    return mask_padded_vocab(cfg, logits), new_pool, cache_len + 1
+    return _head_logits(cfg, params, h), new_pool, cache_len + 1
 
 
 def decode_step_paged_sample(
@@ -724,8 +736,10 @@ def decode_step_paged_sample(
     h, new_pool = _decode_hidden(
         cfg, params, token, pool, cache_len, unroll=unroll,
         page_tables=page_tables, write_enable=write_enable)
-    tok = ops.fused_sample_rows(
-        h, _head_matrix(cfg, params), keys, temps, vocab_size=cfg.vocab_size)
+    with jax.named_scope("head"):
+        tok = ops.fused_sample_rows(
+            h, _head_matrix(cfg, params), keys, temps,
+            vocab_size=cfg.vocab_size)
     return tok, new_pool, cache_len + 1
 
 
@@ -759,8 +773,7 @@ def prefill_chunk(
         cfg, params, h, None, mode="prefill_chunk", caches=caches,
         chunk_offset=offset, unroll=unroll,
     )
-    logits = (h[:, -1] @ _head_matrix(cfg, params)).astype(jnp.float32)
-    return mask_padded_vocab(cfg, logits), new_caches
+    return _head_logits(cfg, params, h[:, -1]), new_caches
 
 
 def gather_cache_rows(caches, slots: jax.Array):

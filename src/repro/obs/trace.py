@@ -8,6 +8,13 @@ in-memory ring buffer, and exports them as Chrome trace-event JSON
 2-host fleet run renders as two stacked host lanes with dag/rollout/fleet
 sub-lanes each.
 
+An enabled tracer also writes every span into the JAX profiler's trace, as
+a ``jax.profiler.TraceAnnotation`` of the span's name whose event stats are
+its attributes plus :data:`PROFILER_MARK` = category. While a profiler
+trace runs (``jax.profiler.start_trace``), the program's spans then sit on
+the device trace's clock beside the device's ops, and a reader selects them
+by that stat. With no profiler trace running an annotation records nothing.
+
 Disabled tracing is a true no-op: ``Tracer(enabled=False).span(...)``
 returns a shared singleton context manager whose enter/exit/``set`` do
 nothing and allocate nothing — instrumented code pays a dict-free function
@@ -16,7 +23,7 @@ call, not a span record (the overhead bound is test-asserted).
 Instrumented call sites reach the tracer through the module-global
 :func:`get_tracer`, which defaults to the disabled :data:`NULL_TRACER`;
 ``build_pipeline`` installs a live tracer via :func:`set_tracer` when
-``ObsConfig.enabled`` is set. Timestamps are ``perf_counter`` deltas
+``ObsConfig.enabled`` is set. Ring timestamps are ``perf_counter`` deltas
 anchored to the wall clock at tracer construction, so traces exported by
 co-located host processes (the simulated-fleet harness) line up on one
 Perfetto timeline.
@@ -46,11 +53,16 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# the event stat that marks a span in the profiler's trace; its value is the
+# span's category
+PROFILER_MARK = "obs_cat"
+
 
 class _Span:
-    """One live span: records itself into the tracer's ring on exit."""
+    """One live span: a profiler annotation while open; records itself into
+    the tracer's ring on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: Dict[str, Any]):
@@ -59,8 +71,14 @@ class _Span:
         self.cat = cat
         self.attrs = attrs
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation  # jax loads when recording
+
+        self._annotation = TraceAnnotation(
+            self.name, **self.attrs, **{PROFILER_MARK: self.cat})
+        self._annotation.__enter__()
         self._t0 = self._tracer.clock()
         return self
 
@@ -68,11 +86,14 @@ class _Span:
         self._tracer._record(
             self.name, self.cat, self._t0,
             self._tracer.clock() - self._t0, self.attrs)
+        self._annotation.__exit__(*exc)
         return False
 
     def set(self, **attrs) -> None:
-        """Attach/overwrite attributes mid-span (``args`` in the export)."""
+        """Attach/overwrite attributes mid-span (``args`` in the export,
+        event stats in the profiler's trace)."""
         self.attrs.update(attrs)
+        self._annotation.set_metadata(**attrs)
 
 
 class Tracer:

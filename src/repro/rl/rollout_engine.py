@@ -500,6 +500,11 @@ class ContinuousRolloutEngine:
         ``generate(budgets=...)``, but here a capped sequence *frees its
         slot* instead of padding out the scan. Under an env, the cap applies
         per turn (jointly with ``turn_budget``)."""
+        with get_tracer().span("rollout/generate", cat="rollout",
+                               seqs=prompts.shape[0]):
+            return self._generate(params, prompts, key, budgets)
+
+    def _generate(self, params, prompts, key, budgets) -> RolloutResult:
         t_start = time.perf_counter()
         prompts_np = np.asarray(jax.device_get(prompts), np.int32)
         B, Lp = prompts_np.shape
@@ -589,249 +594,262 @@ class ContinuousRolloutEngine:
             burst = self._burst_jit[(S, smax)] = self._make_burst(S)
 
         while completed < B:
-            # one bundled host sync per visit: flush state for every slot
-            done_h, resp_len_h, out_tok_h, out_lp_h = jax.device_get(
-                (done, resp_len, out_tok, out_lp))
-            # flush finished slots: single-turn -> results; env -> step the
-            # episode and either finalize or re-enqueue a continuation
-            # (KV rows for every continuing slot are gathered in ONE device
-            # call after the loop, then sliced per episode)
-            pending_conts: List[Tuple[int, int, np.ndarray]] = []
-            for s in range(S):
-                if not (done_h[s] and slot_seq[s] >= 0):
-                    continue
-                row = slot_seq[s]
-                slot_seq[s] = -1
-                if not env_on:
-                    res_tok[row] = out_tok_h[s]
-                    res_lp[row] = out_lp_h[s]
-                    res_len[row] = resp_len_h[s]
-                    completed += 1
-                    continue
-                ep = episodes[row]
-                n = int(resp_len_h[s])
-                rtoks = out_tok_h[s, :n].copy()
-                ep.record_turn(rtoks, out_lp_h[s, :n])
-                row_cache_pos[row] += n - 1  # decode steps this turn
-                obs, r, ep_done, info = ep.env.step(rtoks)
-                ep.reward += float(r)
-                ep.turn += 1
-                ep.infos.append(info or {})
-                total_turns += 1
-                if info and info.get("tool_call"):
-                    tool_calls += 1
-                if ep_done or ep.turn >= max_turns:
-                    completed += 1
-                    continue
-                obs = np.asarray(obs, np.int32).ravel()[: self.obs_budget]
-                ep.record_obs(obs)
-                # the last response token's KV was never written (it was
-                # sampled, not fed), so it leads the feed; the saved rows
-                # carry the whole shared prefix — nothing is re-prefilled
-                feed = np.concatenate([rtoks[-1:], obs])
-                pending_conts.append((s, row, feed))
-                cont_feed_tokens += len(feed)
-                obs_tokens += len(obs)
-            if pending_conts:
-                gathered = self.model.gather_cache_rows(
-                    caches,
-                    jnp.asarray([s for s, _, _ in pending_conts], jnp.int32))
-                for j, (s, row, feed) in enumerate(pending_conts):
-                    saved = jax.tree.map(
-                        lambda a, j=j: a[:, j:j + 1], gathered)
-                    queue.push(_Continuation(
-                        row, feed, saved, row_cache_pos[row]))
-                    row_cache_pos[row] += len(feed)
-            if completed >= B:
-                break
-            # refill every free slot, one jitted call per homogeneous batch
-            # (continuations first, then fresh-prompt length buckets)
-            free = [s for s in range(S) if slot_seq[s] < 0]
-            while free and len(queue):
-                kind, L, items = queue.pop_work(len(free))
-                lanes, free = free[: len(items)], free[len(items):]
-                # pad the batch to the next power of two (capped at the
-                # pool size), not the full pool: a late-stream single-slot
-                # refill runs 1 lane, not num_slots — and a full-pool fill
-                # keeps the exact pool shape, which is what the lockstep-
-                # equivalence schedule runs
-                R = 1
-                while R < len(items):
-                    R *= 2
-                R = min(R, S)
-                slots_arr = jnp.asarray(
-                    np.concatenate([lanes, np.full(R - len(lanes), S)])
-                    .astype(np.int32)
+            with get_tracer().span("rollout/visit", cat="rollout",
+                                   visit=bursts) as visit:
+                # one bundled host sync per visit: flush every slot's state
+                done_h, resp_len_h, out_tok_h, out_lp_h = jax.device_get(
+                    (done, resp_len, out_tok, out_lp))
+                # flush finished slots: single-turn -> results; env -> step
+                # the episode and either finalize or re-enqueue a
+                # continuation (KV rows for every continuing slot are
+                # gathered in ONE device call after the loop, then sliced per
+                # episode)
+                pending_conts: List[Tuple[int, int, np.ndarray]] = []
+                for s in range(S):
+                    if not (done_h[s] and slot_seq[s] >= 0):
+                        continue
+                    row = slot_seq[s]
+                    slot_seq[s] = -1
+                    if not env_on:
+                        res_tok[row] = out_tok_h[s]
+                        res_lp[row] = out_lp_h[s]
+                        res_len[row] = resp_len_h[s]
+                        completed += 1
+                        continue
+                    ep = episodes[row]
+                    n = int(resp_len_h[s])
+                    rtoks = out_tok_h[s, :n].copy()
+                    ep.record_turn(rtoks, out_lp_h[s, :n])
+                    row_cache_pos[row] += n - 1  # decode steps this turn
+                    obs, r, ep_done, info = ep.env.step(rtoks)
+                    ep.reward += float(r)
+                    ep.turn += 1
+                    ep.infos.append(info or {})
+                    total_turns += 1
+                    if info and info.get("tool_call"):
+                        tool_calls += 1
+                    if ep_done or ep.turn >= max_turns:
+                        completed += 1
+                        continue
+                    obs = np.asarray(obs, np.int32).ravel()[
+                        : self.obs_budget]
+                    ep.record_obs(obs)
+                    # the last response token's KV was never written (it was
+                    # sampled, not fed), so it leads the feed; the saved rows
+                    # carry the whole shared prefix — nothing is re-prefilled
+                    feed = np.concatenate([rtoks[-1:], obs])
+                    pending_conts.append((s, row, feed))
+                    cont_feed_tokens += len(feed)
+                    obs_tokens += len(obs)
+                if pending_conts:
+                    gathered = self.model.gather_cache_rows(
+                        caches,
+                        jnp.asarray([s for s, _, _ in pending_conts],
+                                    jnp.int32))
+                    for j, (s, row, feed) in enumerate(pending_conts):
+                        saved = jax.tree.map(
+                            lambda a, j=j: a[:, j:j + 1], gathered)
+                        queue.push(_Continuation(
+                            row, feed, saved, row_cache_pos[row]))
+                        row_cache_pos[row] += len(feed)
+                visit.set(completed=completed)
+                if completed >= B:
+                    break
+                # refill every free slot, one jitted call per homogeneous
+                # batch (continuations first, then fresh-prompt length
+                # buckets)
+                free = [s for s in range(S) if slot_seq[s] < 0]
+                while free and len(queue):
+                    kind, L, items = queue.pop_work(len(free))
+                    lanes, free = free[: len(items)], free[len(items):]
+                    # pad the batch to the next power of two (capped at the
+                    # pool size), not the full pool: a late-stream
+                    # single-slot refill runs 1 lane, not num_slots — and a
+                    # full-pool fill keeps the exact pool shape, which is
+                    # what the lockstep-equivalence schedule runs
+                    R = 1
+                    while R < len(items):
+                        R *= 2
+                    R = min(R, S)
+                    slots_arr = jnp.asarray(
+                        np.concatenate([lanes, np.full(R - len(lanes), S)])
+                        .astype(np.int32)
+                    )
+                    lane_budget = np.full(R, max_new, np.int32)
+                    if kind == "prefill":
+                        idxs = items
+                        batch = np.zeros((R, L), np.int32)
+                        batch[: len(idxs)] = queue.prompts[idxs][:, :L]
+                        lane_budget[: len(idxs)] = budgets_np[idxs]
+                        rk = (k0 if refills == 0
+                              else jax.random.fold_in(k0, refills))
+                        rf = self._refill_jit.get((R, L, smax))
+                        if rf is None:
+                            rf = self._refill_jit[(R, L, smax)] = \
+                                self._make_refill(R, L, smax)
+                        with get_tracer().span("rollout/prefill",
+                                               cat="rollout", lanes=R,
+                                               width=L, seqs=len(idxs)):
+                            (caches, cur_tok, cache_len, resp_len, done,
+                             budget, out_tok, out_lp) = rf(
+                                params, caches, jnp.asarray(batch), slots_arr,
+                                jnp.asarray(lane_budget), rk,
+                                cur_tok, cache_len, resp_len, done, budget,
+                                out_tok, out_lp,
+                            )
+                        for lane, seq in zip(lanes, idxs):
+                            slot_seq[lane] = seq
+                            row_cache_pos[seq] = L
+                        refills += 1
+                        # count the lanes the prefill actually executed
+                        # (incl. the pow2 padding lanes) so prefill_waste
+                        # reflects real compute
+                        prefill_lane_tokens += R * L
+                    else:  # continuation: feed tokens only, saved KV reused
+                        feed = np.zeros((R, L), np.int32)
+                        start_len = np.zeros(R, np.int64)
+                        for j, c in enumerate(items):
+                            feed[j] = c.feed
+                            start_len[j] = c.cache_len
+                            lane_budget[j] = budgets_np[c.row]
+                        rows = self._stack_cont_rows(items, R)
+                        ck = jax.random.fold_in(k0, 1_000_000 + cont_refills)
+                        cf = self._cont_jit.get((R, L, smax))
+                        if cf is None:
+                            cf = self._cont_jit[(R, L, smax)] = \
+                                self._make_continue(R, L, smax)
+                        with get_tracer().span("rollout/refill",
+                                               cat="rollout", lanes=R,
+                                               width=L, conts=len(items)):
+                            (caches, cur_tok, cache_len, resp_len, done,
+                             budget, out_tok, out_lp) = cf(
+                                params, caches, rows, slots_arr,
+                                jnp.asarray(feed),
+                                jnp.asarray(start_len.astype(np.int32)),
+                                jnp.asarray(lane_budget), ck,
+                                cur_tok, cache_len, resp_len, done, budget,
+                                out_tok, out_lp,
+                            )
+                        for lane, c in zip(lanes, items):
+                            slot_seq[lane] = c.row
+                        cont_refills += 1
+                if not any(slot_seq[s] >= 0 for s in range(S)):
+                    break  # queue drained and nothing in flight
+                # a lane refilled immediately-done (EOS at its first token /
+                # budget 1) is counted in the burst's n_done_entry, so the
+                # loop below won't mistake it for a fresh completion; it
+                # flushes on the next visit.
+                # "pending" must also count in-flight episodes that may
+                # re-enter the queue as continuations — otherwise a drained
+                # fresh-prompt queue would hold every finished slot at a
+                # global barrier until the slowest turn completes (lockstep
+                # turns, zero overlap).
+                # Conservative: an episode below its turn cap counts as pending
+                # even if its env ends up finishing it (costs one extra host
+                # visit). Single-turn runs (env off or max_turns == 1) never
+                # have such episodes, so their burst schedule is untouched.
+                cont_possible = env_on and max_turns > 1 and any(
+                    slot_seq[s] >= 0
+                    and episodes[slot_seq[s]].turn + 1 < max_turns
+                    for s in range(S)
                 )
-                lane_budget = np.full(R, max_new, np.int32)
-                if kind == "prefill":
-                    idxs = items
-                    batch = np.zeros((R, L), np.int32)
-                    batch[: len(idxs)] = queue.prompts[idxs][:, :L]
-                    lane_budget[: len(idxs)] = budgets_np[idxs]
-                    rk = (k0 if refills == 0
-                          else jax.random.fold_in(k0, refills))
-                    rf = self._refill_jit.get((R, L, smax))
-                    if rf is None:
-                        rf = self._refill_jit[(R, L, smax)] = \
-                            self._make_refill(R, L, smax)
-                    with get_tracer().span("rollout/prefill", cat="rollout",
-                                           lanes=R, width=L,
-                                           seqs=len(idxs)):
-                        (caches, cur_tok, cache_len, resp_len, done, budget,
-                         out_tok, out_lp) = rf(
-                            params, caches, jnp.asarray(batch), slots_arr,
-                            jnp.asarray(lane_budget), rk,
-                            cur_tok, cache_len, resp_len, done, budget,
-                            out_tok, out_lp,
-                        )
-                    for lane, seq in zip(lanes, idxs):
-                        slot_seq[lane] = seq
-                        row_cache_pos[seq] = L
-                    refills += 1
-                    # count the lanes the prefill actually executed (incl.
-                    # the pow2 padding lanes) so prefill_waste reflects
-                    # real compute
-                    prefill_lane_tokens += R * L
-                else:  # continuation: feed tokens only, saved KV reused
-                    feed = np.zeros((R, L), np.int32)
-                    start_len = np.zeros(R, np.int64)
-                    for j, c in enumerate(items):
-                        feed[j] = c.feed
-                        start_len[j] = c.cache_len
-                        lane_budget[j] = budgets_np[c.row]
-                    rows = self._stack_cont_rows(items, R)
-                    ck = jax.random.fold_in(k0, 1_000_000 + cont_refills)
-                    cf = self._cont_jit.get((R, L, smax))
-                    if cf is None:
-                        cf = self._cont_jit[(R, L, smax)] = \
-                            self._make_continue(R, L, smax)
-                    with get_tracer().span("rollout/refill", cat="rollout",
-                                           lanes=R, width=L,
-                                           conts=len(items)):
-                        (caches, cur_tok, cache_len, resp_len, done, budget,
-                         out_tok, out_lp) = cf(
-                            params, caches, rows, slots_arr, jnp.asarray(feed),
-                            jnp.asarray(start_len.astype(np.int32)),
-                            jnp.asarray(lane_budget), ck,
-                            cur_tok, cache_len, resp_len, done, budget,
-                            out_tok, out_lp,
-                        )
-                    for lane, c in zip(lanes, items):
-                        slot_seq[lane] = c.row
-                    cont_refills += 1
-            if not any(slot_seq[s] >= 0 for s in range(S)):
-                break  # queue drained and nothing in flight
-            # a lane refilled immediately-done (EOS at its first token /
-            # budget 1) is counted in the burst's n_done_entry, so the loop
-            # below won't mistake it for a fresh completion; it flushes on
-            # the next visit.
-            # "pending" must also count in-flight episodes that may re-enter
-            # the queue as continuations — otherwise a drained fresh-prompt
-            # queue would hold every finished slot at a global barrier until
-            # the slowest turn completes (lockstep turns, zero overlap).
-            # Conservative: an episode below its turn cap counts as pending
-            # even if its env ends up finishing it (costs one extra host
-            # visit). Single-turn runs (env off or max_turns == 1) never
-            # have such episodes, so their burst schedule is untouched.
-            cont_possible = env_on and max_turns > 1 and any(
-                slot_seq[s] >= 0
-                and episodes[slot_seq[s]].turn + 1 < max_turns
-                for s in range(S)
-            )
-            has_pending = jnp.asarray(len(queue) > 0 or cont_possible)
-            with get_tracer().span("rollout/decode", cat="rollout",
-                                   burst=bursts, completed=completed):
-                (caches, cur_tok, cache_len, resp_len, done, budget,
-                 out_tok, out_lp, t, occ) = burst(
-                    params, caches, cur_tok, cache_len, resp_len, done,
-                    budget, out_tok, out_lp, t, occ, step_keys, k2,
-                    has_pending,
-                )
-            bursts += 1
+                has_pending = jnp.asarray(len(queue) > 0 or cont_possible)
+                with get_tracer().span("rollout/decode", cat="rollout",
+                                       burst=bursts, completed=completed):
+                    (caches, cur_tok, cache_len, resp_len, done, budget,
+                     out_tok, out_lp, t, occ) = burst(
+                        params, caches, cur_tok, cache_len, resp_len, done,
+                        budget, out_tok, out_lp, t, occ, step_keys, k2,
+                        has_pending,
+                    )
+                bursts += 1
 
         # assemble RolloutResult in dataset order ------------------------- #
-        if not env_on:
-            Lmax = Lp + max_new
-            tokens = np.concatenate([prompts_np, res_tok], axis=1)
-            mask = np.zeros((B, Lmax), bool)
-            for b in range(B):
-                mask[b, Lp: Lp + res_len[b]] = True
-            old_lp = np.concatenate(
-                [np.zeros((B, Lp), np.float32), res_lp], axis=1)
-            roles = None
-            total_turns = completed  # one turn per sequence
-            self.last_env = None
-        else:
-            Lmax = Lp + max_turns * max_new + (max_turns - 1) * self.obs_budget
-            tokens = np.full((B, Lmax), self.pad_id, np.int32)
-            tokens[:, :Lp] = queue_rows
-            roles = np.zeros((B, Lmax), np.int8)
-            old_lp = np.zeros((B, Lmax), np.float32)
-            rewards = np.zeros(B, np.float32)
-            turns = np.zeros(B, np.int32)
-            for b, ep in enumerate(episodes):
-                n = len(ep.toks)
-                tokens[b, Lp: Lp + n] = ep.toks
-                roles[b, Lp: Lp + n] = ep.roles
-                old_lp[b, Lp: Lp + n] = ep.lps
-                rewards[b] = ep.reward
-                turns[b] = ep.turn
-            mask = roles == 1
-            old_lp = np.where(mask, old_lp, 0.0)
-            res_len = mask.sum(axis=1).astype(np.int32)
-            self.last_env = {
-                "rewards": rewards,
-                "turns": turns,
-                "tool_calls": tool_calls,
-            }
+        with get_tracer().span("rollout/assemble", cat="rollout", seqs=B):
+            if not env_on:
+                Lmax = Lp + max_new
+                tokens = np.concatenate([prompts_np, res_tok], axis=1)
+                mask = np.zeros((B, Lmax), bool)
+                for b in range(B):
+                    mask[b, Lp: Lp + res_len[b]] = True
+                old_lp = np.concatenate(
+                    [np.zeros((B, Lp), np.float32), res_lp], axis=1)
+                roles = None
+                total_turns = completed  # one turn per sequence
+                self.last_env = None
+            else:
+                Lmax = (Lp + max_turns * max_new
+                        + (max_turns - 1) * self.obs_budget)
+                tokens = np.full((B, Lmax), self.pad_id, np.int32)
+                tokens[:, :Lp] = queue_rows
+                roles = np.zeros((B, Lmax), np.int8)
+                old_lp = np.zeros((B, Lmax), np.float32)
+                rewards = np.zeros(B, np.float32)
+                turns = np.zeros(B, np.int32)
+                for b, ep in enumerate(episodes):
+                    n = len(ep.toks)
+                    tokens[b, Lp: Lp + n] = ep.toks
+                    roles[b, Lp: Lp + n] = ep.roles
+                    old_lp[b, Lp: Lp + n] = ep.lps
+                    rewards[b] = ep.reward
+                    turns[b] = ep.turn
+                mask = roles == 1
+                old_lp = np.where(mask, old_lp, 0.0)
+                res_len = mask.sum(axis=1).astype(np.int32)
+                self.last_env = {
+                    "rewards": rewards,
+                    "turns": turns,
+                    "tool_calls": tool_calls,
+                }
 
-        wall = time.perf_counter() - t_start
-        steps = int(jax.device_get(t))
-        occ_steps = int(jax.device_get(occ))
-        gen_tokens = int(res_len.sum())
-        # each turn's first token comes from a refill/continuation sample,
-        # not a decode step (single-turn: total_turns == B)
-        decode_tokens = gen_tokens - total_turns
-        lane_steps = S * steps
-        self.last_stats = {
-            "tokens": float(gen_tokens),
-            "wall_s": wall,
-            "tokens_per_s": gen_tokens / wall if wall > 0 else 0.0,
-            "decode_steps": float(steps),
-            "bursts": float(bursts),
-            "refills": float(refills),
-            "num_slots": float(S),
-            "slot_occupancy": occ_steps / lane_steps if lane_steps else 1.0,
-            "padding_waste": (
-                1.0 - decode_tokens / lane_steps if lane_steps else 0.0),
-            "prefill_lane_tokens": float(prefill_lane_tokens),
-            "prefill_true_tokens": float(prefill_true_tokens),
-            "prefill_waste": (
-                1.0 - prefill_true_tokens / prefill_lane_tokens
-                if prefill_lane_tokens else 0.0),
-            # per-turn prefill accounting: turn 1 prefills true prompt
-            # tokens; every later turn feeds ONLY the observation plus one
-            # carried response token through the decode path (KV reuse —
-            # the acceptance metric for the episode loop)
-            "prefill_tokens": float(prefill_true_tokens + cont_feed_tokens),
-            "prefill_tokens_turn1": float(prefill_true_tokens),
-            "prefill_tokens_turn2plus": float(cont_feed_tokens),
-            "obs_tokens": float(obs_tokens),
-            "cont_refills": float(cont_refills),
-            "turns": float(total_turns),
-        }
-        if env_on:
-            self.last_stats["turns_mean"] = (
-                total_turns / B if B else 0.0)
-            self.last_stats["tool_calls"] = float(tool_calls)
-        return RolloutResult(
-            jnp.asarray(tokens),
-            jnp.asarray(mask),
-            jnp.asarray(old_lp),
-            jnp.asarray(res_len.astype(np.int32)),
-            None if roles is None else jnp.asarray(roles),
-        )
+            wall = time.perf_counter() - t_start
+            steps = int(jax.device_get(t))
+            occ_steps = int(jax.device_get(occ))
+            gen_tokens = int(res_len.sum())
+            # each turn's first token comes from a refill/continuation sample,
+            # not a decode step (single-turn: total_turns == B)
+            decode_tokens = gen_tokens - total_turns
+            lane_steps = S * steps
+            self.last_stats = {
+                "tokens": float(gen_tokens),
+                "wall_s": wall,
+                "tokens_per_s": gen_tokens / wall if wall > 0 else 0.0,
+                "decode_steps": float(steps),
+                "bursts": float(bursts),
+                "refills": float(refills),
+                "num_slots": float(S),
+                "slot_occupancy": (
+                    occ_steps / lane_steps if lane_steps else 1.0),
+                "padding_waste": (
+                    1.0 - decode_tokens / lane_steps if lane_steps else 0.0),
+                "prefill_lane_tokens": float(prefill_lane_tokens),
+                "prefill_true_tokens": float(prefill_true_tokens),
+                "prefill_waste": (
+                    1.0 - prefill_true_tokens / prefill_lane_tokens
+                    if prefill_lane_tokens else 0.0),
+                # per-turn prefill accounting: turn 1 prefills true prompt
+                # tokens; every later turn feeds ONLY the observation plus one
+                # carried response token through the decode path (KV reuse —
+                # the acceptance metric for the episode loop)
+                "prefill_tokens": float(
+                    prefill_true_tokens + cont_feed_tokens),
+                "prefill_tokens_turn1": float(prefill_true_tokens),
+                "prefill_tokens_turn2plus": float(cont_feed_tokens),
+                "obs_tokens": float(obs_tokens),
+                "cont_refills": float(cont_refills),
+                "turns": float(total_turns),
+            }
+            if env_on:
+                self.last_stats["turns_mean"] = (
+                    total_turns / B if B else 0.0)
+                self.last_stats["tool_calls"] = float(tool_calls)
+            return RolloutResult(
+                jnp.asarray(tokens),
+                jnp.asarray(mask),
+                jnp.asarray(old_lp),
+                jnp.asarray(res_len.astype(np.int32)),
+                None if roles is None else jnp.asarray(roles),
+            )
 
 
 def lockstep_waste(lengths: np.ndarray, max_new: int) -> float:

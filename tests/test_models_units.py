@@ -132,3 +132,45 @@ def test_ring_cache_width():
     cfg2 = ARCHS["deepseek-67b"]
     caches2 = jax.eval_shape(lambda: lm.init_caches(cfg2, batch=1, smax=8192))
     assert caches2[0]["k"].shape[2] == 8192  # full attention keeps smax
+
+
+def _dot_scopes(fn, *args):
+    """The JAX name stack of every dot in ``fn``'s compiled program."""
+    import re
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [m.group(1) for m in re.finditer(
+        r' dot\(.*?op_name="([^"]*)"', text)]
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-7b", "mamba2-2.7b"])
+def test_layer_scopes_name_every_matmul(arch):
+    """Each matmul of decode (sampler fused), prefill and the loss's
+    forward and backward lies under the block's ``mixer`` or ``mlp``
+    named scope or the ``head`` scope; the backward's through its
+    transpose name stack. Device traces name the ops by these."""
+    import re
+
+    cfg = reduced(ARCHS[arch], vocab_size=260, num_layers=2, d_model=64,
+                  num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128,
+                  ssm_state=16, ssm_headdim=16)
+    params = lm.init(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.ones((2, 8), jnp.int32)
+    caches = lm.init_caches(cfg, 2, 16)
+    cache_len = jnp.full((2,), 8, jnp.int32)
+    labels = jnp.ones((2, 8), jnp.int32)
+    layer = re.compile(r"(^|[/(])(mixer|mlp|head)([/)]|$)")
+    decode = _dot_scopes(
+        lambda p, t, c, n, k: lm.decode_step_sample(cfg, p, t, c, n, k, 1.0),
+        params, tokens[:, 0], caches, cache_len, jax.random.PRNGKey(1))
+    prefill = _dot_scopes(lambda p, t: lm.prefill(cfg, p, t, smax=16)[0],
+                          params, tokens)
+    grad = _dot_scopes(jax.grad(lambda p: lm.loss_fn(
+        cfg, p, {"tokens": tokens, "labels": labels})[0]), params)
+    for scopes in (decode, prefill, grad):
+        assert scopes and all(layer.search(s) for s in scopes), scopes
+    for name in ("mixer", "head") + (("mlp",) if arch == "qwen2.5-7b"
+                                     else ()):
+        named = re.compile(rf"(^|[/(]){name}([/)]|$)")
+        assert any(named.search(s) for s in decode), name
+        assert any(named.search(s) and "transpose(" in s for s in grad), name

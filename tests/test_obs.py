@@ -150,6 +150,46 @@ def test_set_tracer_save_restore():
     assert get_tracer() is not mine
 
 
+def test_enabled_spans_reach_the_profiler_trace_marked(tmp_path):
+    """An enabled tracer writes each span into the JAX profiler's trace as
+    well as into its ring: named for the span, with its category under
+    PROFILER_MARK and its attributes (those set mid-span too) as event
+    stats, on the profiler's clock. With no profiler trace running, the
+    ring alone records."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import PROFILER_MARK
+
+    t = Tracer(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("rollout/visit", cat="rollout", visit=2) as sp:
+            with t.span("rollout/decode", cat="rollout", burst=2):
+                pass
+            sp.set(completed=5)
+    finally:
+        jax.profiler.stop_trace()
+    with t.span("node/train", cat="dag"):
+        pass
+    assert t.num_events == 3
+    (xplane,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found = {e.name: (e.start_ns, e.duration_ns,
+                      {k: str(v) for k, v in e.stats})
+             for plane in ProfileData.from_file(xplane).planes
+             for line in plane.lines for e in line.events
+             if PROFILER_MARK in {k for k, _ in e.stats}}
+    assert set(found) == {"rollout/visit", "rollout/decode"}
+    visit, decode = found["rollout/visit"], found["rollout/decode"]
+    assert visit[2] == {PROFILER_MARK: "rollout", "visit": "2",
+                        "completed": "5"}
+    assert decode[2] == {PROFILER_MARK: "rollout", "burst": "2"}
+    assert visit[0] <= decode[0]
+    assert decode[0] + decode[1] <= visit[0] + visit[1]
+
+
 # --------------------------------------------------------------------- #
 # metrics registry
 # --------------------------------------------------------------------- #
