@@ -36,6 +36,19 @@ def residual_entries():
     return ("dp", "tp", None) if sequence_parallel() else ("dp", None, None)
 
 
+def tp_shards(dim: int) -> int:
+    """How many shards ``constrain``'s 'tp' entry splits a dimension of
+    size ``dim`` into under the ambient mesh: the `model` axis's size where
+    it divides ``dim``, else 1 (also with no mesh, or inside shard_map)."""
+    from repro.utils.jax_compat import ambient_mesh
+
+    mesh = ambient_mesh()
+    if mesh is None or "model" not in mesh.axis_names:
+        return 1
+    n = dict(mesh.shape)["model"]
+    return n if dim % n == 0 else 1
+
+
 def constrain(x: jax.Array, *entries) -> jax.Array:
     """entries: 'dp' | 'tp' | None per dim (trailing dims may be omitted).
 

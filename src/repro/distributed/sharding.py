@@ -188,15 +188,11 @@ def cache_specs(cfg: ModelConfig, mesh: Mesh, batch: int, caches_shape) -> Any:
                 name = entry.key
                 break
         shape = leaf.shape
-        if name in ("k", "v", "mk", "mv"):
-            # (N, B, W, KVH, hd) or (L, B, W, KVH, hd)
-            seq = shape[-3]
+        if name in ("k", "v", "mk", "mv", "k_scale", "v_scale"):
+            # (N, B, W, KVH*hd), (N|L, B, W, KVH, hd) or scales (N, B, W, KVH)
+            seq = shape[2]
             seq_ax = ctx_axes if seq % _size(mesh, ctx_axes) == 0 else None
-            return P(*[None] * (len(shape) - 4), dp, seq_ax, None, None)
-        if name in ("k_scale", "v_scale"):  # (N, B, W, KVH)
-            seq = shape[-2]
-            seq_ax = ctx_axes if seq % _size(mesh, ctx_axes) == 0 else None
-            return P(*[None] * (len(shape) - 3), dp, seq_ax, None)
+            return P(None, dp, seq_ax, *[None] * (len(shape) - 3))
         if name == "ssm":  # (N, B, nh, hd, ds)
             nh_ax = "model" if shape[-3] % mesh.shape["model"] == 0 else None
             return P(*[None] * (len(shape) - 4), dp, nh_ax, None, None)

@@ -10,13 +10,16 @@ that shards of a sequence-sharded cache can be combined exactly with
 cache_len is a scalar-prefetch operand ((B,) int32): number of valid slots
 per sequence; ``pos_offset`` is the absolute position of local cache slot 0.
 
-Layouts: the caches keep their (B, S, KVH, D) / (P, page_size, KVH, D) shape
-and the wrappers view them lane-folded, (…, S, KVH*D) — a free reshape, no
-per-step transpose of the cache — so each K/V tile is a (block_s, D) slab
-picked by the kv-head index along the last axis. Mosaic needs a tile's last
-two dims divisible by (sublanes, 128) or equal to the array's, so compiled
-runs need ``D % 128 == 0`` and S blocks a multiple of the dtype's sublane
-count (8 for f32, 16 for bf16, 32 for int8); interpret mode takes any D.
+Layouts: the kernels read K/V lane-folded, (…, S, KVH*D), so each K/V tile
+is a (block_s, D) slab picked by the kv-head index along the last axis; the
+wrappers view a (B, S, KVH, D) cache or (P, page_size, KVH, D) pool so. The
+dense kernel also reads a decode loop's stacked arena (N, B, S, KVH*D) in
+place: the layer index is a second scalar-prefetch operand that the K/V
+index map reads, so no layer slice or relayout is materialized. Mosaic
+needs a tile's last two dims divisible by (sublanes, 128) or equal to the
+array's, so compiled runs need ``D % 128 == 0`` and S blocks a multiple of
+the dtype's sublane count (8 for f32, 16 for bf16, 32 for int8); interpret
+mode takes any D.
 """
 from __future__ import annotations
 
@@ -334,21 +337,36 @@ def decode_attention_quant(
                k_scale, v_scale)
 
 
+def _arena_kernel(len_ref, layer_ref, *refs, **kw):
+    del layer_ref  # read by the K/V index map
+    _kernel(len_ref, *refs, **kw)
+
+
 def decode_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     cache_len: jax.Array,
     *,
+    layer: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     window: Optional[int] = None,
     pos_offset: int = 0,
     block_s: int = 512,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (o (B,H,D), lse (B,H))."""
+    """Returns (o (B,H,D), lse (B,H)).
+
+    ``k``/``v`` are one layer's cache, (B, S, KVH, D), or, with ``layer``
+    (an int32 scalar, traced or not), a stacked lane-folded arena
+    (N, B, S, KVH*D) of which layer ``layer`` is read."""
     B, H, D = q.shape
-    _, S, KVH, _ = k.shape
+    if layer is None:
+        _, S, KVH, _ = k.shape
+        k, v = (a.reshape(1, B, S, KVH * D) for a in (k, v))
+        layer = 0
+    else:
+        S, KVH = k.shape[2], k.shape[3] // D
     assert H % KVH == 0
     check_lane_width(D, interpret)
     group = H // KVH
@@ -357,7 +375,7 @@ def decode_attention(
     ns = S // block_s
 
     kernel = functools.partial(
-        _kernel,
+        _arena_kernel,
         scale=scale,
         block_s=block_s,
         num_s_blocks=ns,
@@ -370,15 +388,17 @@ def decode_attention(
         pos_offset=pos_offset, window=window,
     )
     kv_spec = pl.BlockSpec(
-        (None, block_s, D), lambda b, kh, si, lens: (b, ragged(si, lens[b]), kh))
+        (None, None, block_s, D),
+        lambda b, kh, si, lens, n: (n[0], b, ragged(si, lens[b]), kh))
     run = _decode_call(
-        kernel, grid=(B, KVH, ns), num_scalar_prefetch=1,
-        q_map=lambda b, kh, si, lens: (b, kh, 0, 0),
+        kernel, grid=(B, KVH, ns), num_scalar_prefetch=2,
+        q_map=lambda b, kh, si, lens, n: (b, kh, 0, 0),
         kv_specs=[kv_spec, kv_spec],
         B=B, KVH=KVH, group=group, D=D, dtype=q.dtype, interpret=interpret)
     # q heads are kv-major contiguous: (B, H, D) -> (B, KVH, group, D)
-    return run(cache_len.astype(jnp.int32), q.reshape(B, KVH, group, D),
-               k.reshape(B, S, KVH * D), v.reshape(B, S, KVH * D))
+    return run(cache_len.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32),
+               q.reshape(B, KVH, group, D), k, v)
 
 
 def _paged_kernel(

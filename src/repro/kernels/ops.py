@@ -53,26 +53,33 @@ def current_mode() -> str:
     return mode
 
 
-def _batch_parallel(kernel, batched):
+def _batch_parallel(kernel, batch_axes):
     """``kernel`` run per data shard of the ambient mesh.
 
-    ``batched`` flags, per positional argument, whether axis 0 is the batch
-    (split over every mesh axis but ``model``) or the argument is replicated
-    (weights, the page pool). Every output carries the batch on axis 0. A
-    batch the data axes do not divide runs whole on every device. Without a
-    mesh of several devices the kernel is returned as it is."""
+    ``batch_axes`` gives, per positional argument, the axis that holds the
+    batch (split over every mesh axis but ``model``), or None for an
+    argument that is replicated (weights, the page pool, a layer index).
+    Every output carries the batch on axis 0. A batch the data axes do not
+    divide runs whole on every device. Without a mesh of several devices the
+    kernel is returned as it is."""
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1:
         return kernel
     axes = tuple(a for a in mesh.axis_names if a != "model")
 
     def run(*args):
-        batch = args[batched.index(True)].shape[0]
-        spec = P(axes) if batch % math.prod(mesh.shape[a] for a in axes) == 0 \
-            else P()
+        i = next(i for i, a in enumerate(batch_axes) if a is not None)
+        batch = args[i].shape[batch_axes[i]]
+        split = batch % math.prod(mesh.shape[a] for a in axes) == 0
+
+        def spec(axis):
+            if axis is None or not split:
+                return P()
+            return P(*[None] * axis, axes)
+
         return shard_map(
-            kernel, mesh=mesh, out_specs=spec, check_vma=False,
-            in_specs=tuple(spec if b else P() for b in batched))(*args)
+            kernel, mesh=mesh, out_specs=spec(0), check_vma=False,
+            in_specs=tuple(spec(a) for a in batch_axes))(*args)
 
     return run
 
@@ -110,16 +117,21 @@ def flash_attention(
         return reference(q, k, v)
     kernel = _batch_parallel(functools.partial(
         _fa.flash_attention, interpret=(mode == "interpret"), **opts),
-        (True, True, True))
+        (0, 0, 0))
     return _with_ref_vjp("flash_attention", kernel, reference)(q, k, v)
 
 
 def decode_attention(
-    q, k, v, cache_len, *, scale=None, window=None, pos_offset=0
+    q, k, v, cache_len, *, layer=None, scale=None, window=None, pos_offset=0
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (o, lse) in every mode (shard-combinable)."""
+    """Returns (o, lse) in every mode (shard-combinable). ``k``/``v`` are
+    one layer's (B, S, KVH, D) cache or, with ``layer``, a decode loop's
+    stacked lane-folded arena (N, B, S, KVH*D), read at layer ``layer``."""
     mode = current_mode()
     if mode == "ref":
+        if layer is not None:
+            D = q.shape[-1]
+            k, v = (a[layer].reshape(*a.shape[1:-1], -1, D) for a in (k, v))
         return _ref.decode_attention(
             q,
             k,
@@ -133,7 +145,11 @@ def decode_attention(
     kernel = functools.partial(
         _da.decode_attention, scale=scale, window=window,
         pos_offset=pos_offset, interpret=(mode == "interpret"))
-    return _batch_parallel(kernel, (True,) * 4)(q, k, v, cache_len)
+    if layer is None:
+        return _batch_parallel(kernel, (0,) * 4)(q, k, v, cache_len)
+    return _batch_parallel(
+        lambda q, k, v, n, i: kernel(q, k, v, n, layer=i),
+        (0, 1, 1, 0, None))(q, k, v, cache_len, jnp.asarray(layer, jnp.int32))
 
 
 def decode_attention_quant(
@@ -153,7 +169,7 @@ def decode_attention_quant(
     kernel = functools.partial(
         _da.decode_attention_quant, scale=scale, window=window,
         pos_offset=pos_offset, interpret=(mode == "interpret"))
-    return _batch_parallel(kernel, (True,) * 6)(
+    return _batch_parallel(kernel, (0,) * 6)(
         q, k, v, k_scale, v_scale, cache_len)
 
 
@@ -172,7 +188,7 @@ def paged_decode_attention(
     kernel = functools.partial(
         _da.paged_decode_attention, scale=scale,
         interpret=(mode == "interpret"))
-    return _batch_parallel(kernel, (True, False, False, True, True))(
+    return _batch_parallel(kernel, (0, None, None, 0, 0))(
         q, pool_k, pool_v, tables, kv_len)
 
 
@@ -230,7 +246,7 @@ def fused_sample_rows(h, w_head, keys, temps, *, vocab_size=None) -> jax.Array:
 def _sampler(vocab_size, mode):
     kernel = functools.partial(_sm.fused_sample, vocab_size=vocab_size,
                                interpret=(mode == "interpret"))
-    return _batch_parallel(kernel, (True, False, True, True))
+    return _batch_parallel(kernel, (0, None, 0, 0))
 
 
 def combine_decode_shards(o_parts, lse_parts):
@@ -248,7 +264,7 @@ def ssd(x, dt, A, Bm, Cm, D, *, chunk=128, return_state=False):
     else:
         kernel = _batch_parallel(functools.partial(
             _ssd.ssd, chunk=chunk, interpret=(mode == "interpret")),
-            (True, True, False, True, True, False))
+            (0, 0, None, 0, 0, None))
         y, h = _with_ref_vjp("ssd", kernel, reference)(x, dt, A, Bm, Cm, D)
     if return_state:
         return y, h
@@ -270,5 +286,5 @@ def rmsnorm(x, w, *, eps: float = 1e-6):
     kernel = functools.partial(
         _rn.rmsnorm, eps=eps, interpret=(mode == "interpret"))
     if x.ndim > 1:
-        kernel = _batch_parallel(kernel, (True, False))
+        kernel = _batch_parallel(kernel, (0, None))
     return _with_ref_vjp("rmsnorm", kernel, reference)(x, w)

@@ -14,9 +14,16 @@ Three execution modes share one backbone:
   decode   — one token per sequence against the caches
 
 Decode caches (per pattern position, stacked over groups):
-  attn  {"k","v"} (N,B,W,KVH,hd) — W = min(Smax, sliding_window): SWA archs get
-        a ring buffer bounded at the window (the long_500k enabler for mixtral)
+  attn  {"k","v"} (N,B,W,KVH*hd) bf16, lane-folded as the decode kernel reads
+        them — W = min(Smax, sliding_window): SWA archs get a ring buffer
+        bounded at the window (the long_500k enabler for mixtral); with
+        ``kv_quant`` int8 (N,B,W,KVH,hd) plus f32 scales (N,B,W,KVH)
   ssm   {"ssm","conv_x","conv_bc"} — constant-size Mamba2 state
+
+Decode carries the whole cache tree through the layer loop and updates it in
+place: layer n's new K/V row of each sequence is written at
+[n, b, cache_len[b]] and the decode kernel reads layer n of the stacked
+arena, so a step moves one row per sequence, not the arena.
 """
 from __future__ import annotations
 
@@ -27,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.constraint import constrain, residual_entries
+from repro.distributed.constraint import constrain, residual_entries, tp_shards
 from repro.kernels import ops
 from repro.models import layers, moe, ssm
 
@@ -111,6 +118,49 @@ def dequant_kv(q: jax.Array, scale: jax.Array) -> jax.Array:
     return (q.astype(jnp.float32) * scale[..., None]).astype(jnp.bfloat16)
 
 
+def _fold(x: jax.Array) -> jax.Array:
+    """(…, KVH, hd) -> the cache's lane-folded (…, KVH*hd)."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _unfold(x: jax.Array, hd: int) -> jax.Array:
+    """Lane-folded (…, KVH*hd) -> (…, KVH, hd)."""
+    return x.reshape(*x.shape[:-1], -1, hd)
+
+
+def _per_layer(step, cache: Params, layer):
+    """Decode for the layouts read one layer at a time (int8 caches, the
+    paged pool, SSM state): ``step`` maps layer ``layer`` of the stacked
+    ``cache`` to (output, new layer), which is written back in place."""
+    take = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+    out, new = step(jax.tree.map(take, cache))
+    return out, jax.tree.map(
+        lambda a, x: jax.lax.dynamic_update_index_in_dim(a, x, layer, 0),
+        cache, new)
+
+
+def _write_rows(arena: jax.Array, layer, slot: jax.Array,
+                rows: jax.Array) -> jax.Array:
+    """Write sequence b's new K or V row ``rows[b]`` at ``[layer, b,
+    slot[b]]`` of the stacked arena (N, B, W, F).
+
+    An indexed update of B rows, done in place on the decode loop's carried
+    buffer; its batch indices are an iota, so GSPMD updates a batch-sharded
+    arena shard by shard. Where the W axis is sharded over `model` a scatter at a traced
+    position made GSPMD all-gather the cache every step (§Perf A-it2), so
+    there the layer is rewritten through an elementwise select instead: one
+    read and write of the layer in HBM, no wire traffic."""
+    rows = rows.astype(arena.dtype)
+    B, W = arena.shape[1], arena.shape[2]
+    if tp_shards(W) == 1:
+        return arena.at[layer, jnp.arange(B), slot].set(rows)
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
+           == slot[:, None])[..., None]
+    cur = jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
+    return jax.lax.dynamic_update_index_in_dim(
+        arena, jnp.where(sel, rows[:, None], cur), layer, 0)
+
+
 def _ring_width(cfg: ModelConfig, smax: int) -> int:
     if cfg.sliding_window is not None:
         return min(smax, cfg.sliding_window)
@@ -129,6 +179,7 @@ def _attn_mixer(
     chunk_offset: Optional[int] = None,
     page_tables: Optional[jax.Array] = None,
     write_enable: Optional[jax.Array] = None,
+    layer=None,
 ):
     if mode == "full":
         return layers.self_attention(cfg, p, h, positions), None
@@ -164,10 +215,13 @@ def _attn_mixer(
                 "k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
         kc, vc = cache["k"], cache["v"]
         assert hi <= kc.shape[1], "chunked prefill past the cache width"
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, chunk_offset, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, chunk_offset, 0, 0))
+        kc = jax.lax.dynamic_update_slice(
+            kc, _fold(k).astype(kc.dtype), (0, chunk_offset, 0))
+        vc = jax.lax.dynamic_update_slice(
+            vc, _fold(v).astype(vc.dtype), (0, chunk_offset, 0))
+        hd = cfg.head_dim
         o = ops.flash_attention(
-            q, kc[:, :hi], vc[:, :hi],
+            q, _unfold(kc[:, :hi], hd), _unfold(vc[:, :hi], hd),
             causal=True, window=cfg.sliding_window, q_offset=chunk_offset,
         )
         return layers.out_proj(cfg, p, o), {"k": kc, "v": vc}
@@ -192,41 +246,34 @@ def _attn_mixer(
             vq, vs = quant_kv(vc)
             return layers.out_proj(cfg, p, o), {
                 "k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-        return layers.out_proj(cfg, p, o), {"k": kc, "v": vc}
+        return layers.out_proj(cfg, p, o), {"k": _fold(kc), "v": _fold(vc)}
 
-    # decode
+    # decode: ``cache`` is this pattern position's stacked tree, ``layer``
+    # the group the loop is at
     assert cache is not None and cache_len is not None
-    B = h.shape[0]
     q, k_new, v_new = layers.qkv_proj(cfg, p, h, cache_len[:, None])
     if page_tables is not None:
-        return _decode_paged(
-            cfg, p, q, k_new, v_new, cache, cache_len, page_tables,
-            write_enable,
-        )
+        return _per_layer(lambda c: _decode_paged(
+            cfg, p, q, k_new, v_new, c, cache_len, page_tables,
+            write_enable), cache, layer)
     if cfg.kv_quant:
-        return _decode_quant(cfg, p, q, k_new, v_new, cache, cache_len)
+        return _per_layer(lambda c: _decode_quant(
+            cfg, p, q, k_new, v_new, c, cache_len), cache, layer)
     kc, vc = cache["k"], cache["v"]
-    W = kc.shape[1]
+    W = kc.shape[2]
     ring = cfg.sliding_window is not None and W <= cfg.sliding_window
     slot = cache_len % W if ring else cache_len
-    # masked write instead of a dynamic scatter: elementwise select keeps
-    # the seq-sharded cache fully in place under GSPMD (a scatter at a
-    # traced index made the partitioner all-gather the cache every step —
-    # §Perf A-it2); costs one cache read+write of HBM locally, zero wire.
-    sel = (jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
-           == slot[:, None])[..., None, None]
-    kc = jnp.where(sel, k_new[:, 0][:, None], kc)
-    vc = jnp.where(sel, v_new[:, 0][:, None], vc)
-    # pin the updated cache to its resident layout (batch x seq-over-model)
-    kc = constrain(kc, "dp", "tp", None, None)
-    vc = constrain(vc, "dp", "tp", None, None)
+    kc = _write_rows(kc, layer, slot, _fold(k_new[:, 0]))
+    vc = _write_rows(vc, layer, slot, _fold(v_new[:, 0]))
+    # pin the arena to its resident layout (batch x seq-over-model)
+    kc = constrain(kc, None, "dp", "tp", None)
+    vc = constrain(vc, None, "dp", "tp", None)
     if ring:
-        eff_len = jnp.minimum(cache_len + 1, W)
-        o, _ = ops.decode_attention(q[:, 0], kc, vc, eff_len, window=None)
+        lens, window = jnp.minimum(cache_len + 1, W), None
     else:
-        o, _ = ops.decode_attention(
-            q[:, 0], kc, vc, cache_len + 1, window=cfg.sliding_window
-        )
+        lens, window = cache_len + 1, cfg.sliding_window
+    o, _ = ops.decode_attention(q[:, 0], kc, vc, lens, window=window,
+                                layer=layer)
     return layers.out_proj(cfg, p, o)[:, None], {"k": kc, "v": vc}
 
 
@@ -278,7 +325,7 @@ def _decode_paged(cfg, p, q, k_new, v_new, cache, cache_len, page_tables,
     engine's admission gate); SWA rings and int8 pools are rejected here."""
     assert cfg.sliding_window is None, "paged decode: SWA unsupported"
     assert not cfg.kv_quant, "paged decode: int8 pool unsupported"
-    kc, vc = cache["k"], cache["v"]
+    kc, vc = cache["k"], cache["v"]  # (P, page_size, KVH*hd), lane-folded
     P, ps = kc.shape[0], kc.shape[1]
     T = page_tables.shape[1]
     pidx = jnp.clip(cache_len // ps, 0, T - 1)
@@ -286,14 +333,16 @@ def _decode_paged(cfg, p, q, k_new, v_new, cache, cache_len, page_tables,
     off = cache_len % ps
     if write_enable is not None:
         page = jnp.where(write_enable, page, P)  # OOB -> dropped below
-    kc = kc.at[page, off].set(k_new[:, 0].astype(kc.dtype), mode="drop")
-    vc = vc.at[page, off].set(v_new[:, 0].astype(vc.dtype), mode="drop")
-    o, _ = ops.paged_decode_attention(q[:, 0], kc, vc, page_tables,
+    kc = kc.at[page, off].set(_fold(k_new[:, 0]).astype(kc.dtype), mode="drop")
+    vc = vc.at[page, off].set(_fold(v_new[:, 0]).astype(vc.dtype), mode="drop")
+    hd = cfg.head_dim
+    o, _ = ops.paged_decode_attention(q[:, 0], _unfold(kc, hd),
+                                      _unfold(vc, hd), page_tables,
                                       cache_len + 1)
     return layers.out_proj(cfg, p, o)[:, None], {"k": kc, "v": vc}
 
 
-def _ssm_mixer(cfg, p, h, mode, cache):
+def _ssm_mixer(cfg, p, h, mode, cache, layer=None):
     if mode == "prefill_chunk":
         raise NotImplementedError(
             "chunked prefill needs SSM state carried between chunks; "
@@ -304,8 +353,8 @@ def _ssm_mixer(cfg, p, h, mode, cache):
     if mode == "prefill":
         out, state = ssm.apply_ssm(cfg, p, h, return_state=True)
         return out, state
-    out, state = ssm.apply_ssm_decode(cfg, p, h, cache)
-    return out, state
+    return _per_layer(lambda c: ssm.apply_ssm_decode(cfg, p, h, c), cache,
+                      layer)
 
 
 def _apply_block(
@@ -321,6 +370,7 @@ def _apply_block(
     chunk_offset: Optional[int] = None,
     page_tables: Optional[jax.Array] = None,
     write_enable: Optional[jax.Array] = None,
+    layer=None,
 ):
     mixer_kind, mlp_kind = kind
     # each sub-layer's residual add sits in its scope, so a matmul fused
@@ -330,10 +380,11 @@ def _apply_block(
         if mixer_kind == "attn":
             mix_out, new_cache = _attn_mixer(
                 cfg, p["attn"], hn, positions, mode, cache, cache_len, smax,
-                chunk_offset, page_tables, write_enable)
+                chunk_offset, page_tables, write_enable, layer)
         else:
             assert page_tables is None, "paged decode: attention-only archs"
-            mix_out, new_cache = _ssm_mixer(cfg, p["ssm"], hn, mode, cache)
+            mix_out, new_cache = _ssm_mixer(cfg, p["ssm"], hn, mode, cache,
+                                            layer)
         if not cfg.parallel_block:
             h = h + mix_out
 
@@ -383,21 +434,31 @@ def backbone(
     ``unroll=True`` replaces the layer-group scan with a Python loop: same
     math, explicit per-layer HLO. Used by the dry-run so cost_analysis()
     counts every layer (XLA prices a while-loop body once) — and by perf
-    variants trading compile time for scheduling freedom."""
+    variants trading compile time for scheduling freedom.
+
+    Decode carries the stacked caches through the loop whole, with the
+    group index beside each group's parameters, and each block updates its
+    layer in place; the other modes scan the caches as per-group slices."""
     P = pattern_length(cfg)
+    N = cfg.num_layers // P
     kinds = cfg.layer_kinds()[:P]
     blocks = params["blocks"]  # list over positions, each stacked over groups
+    decode = mode == "decode"
 
     def body(carry, xs):
-        h, aux = carry
-        group_params, group_caches = xs
+        h, aux, carried = carry
+        if decode:
+            group_params, layer = xs
+            group_caches = carried
+        else:
+            (group_params, group_caches), layer = xs, None
         new_caches = []
         for pos in range(P):
             c_in = None if group_caches is None else group_caches[pos]
             h, a, c_out = _apply_block(
                 cfg, group_params[pos], kinds[pos],
                 h, positions, mode, c_in, cache_len, smax, chunk_offset,
-                page_tables, write_enable,
+                page_tables, write_enable, layer,
             )
             # sequence-parallel residual stream (Megatron-SP): between
             # blocks the seq dim shards over `model`, so the out-proj's TP
@@ -406,31 +467,33 @@ def backbone(
             h = constrain(h, *residual_entries())
             aux = aux + a
             new_caches.append(c_out)
+        if decode:
+            return (h, aux, new_caches), None
         if all(c is None for c in new_caches):
-            return (h, aux), None
-        return (h, aux), new_caches
+            return (h, aux, None), None
+        return (h, aux, None), new_caches
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
-    carry0 = (h, jnp.zeros((), jnp.float32))
+    carry = (h, jnp.zeros((), jnp.float32), caches if decode else None)
+    xs = (blocks, jnp.arange(N)) if decode else (blocks, caches)
     if unroll:
-        N = cfg.num_layers // P
-        carry = carry0
         ys = []
         for i in range(N):
-            xs_i = jax.tree.map(lambda t: t[i], (blocks, caches))
+            xs_i = (jax.tree.map(lambda t: t[i], blocks),
+                    i if decode else jax.tree.map(lambda t: t[i], caches))
             carry, y = body(carry, xs_i)
             ys.append(y)
-        (h, aux) = carry
-        if ys[0] is None:
-            new_caches = None
+        if decode or ys[0] is None:
+            ys = None
         else:
-            new_caches = jax.tree.map(lambda *ts: jnp.stack(ts), *ys)
+            ys = jax.tree.map(lambda *ts: jnp.stack(ts), *ys)
     else:
-        (h, aux), new_caches = jax.lax.scan(body, carry0, (blocks, caches))
+        carry, ys = jax.lax.scan(body, carry, xs)
+    h, aux, carried = carry
     h = layers.apply_norm(cfg, params["final_norm"], h)
-    return h, aux, new_caches
+    return h, aux, carried if decode else ys
 
 
 def embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
@@ -601,8 +664,8 @@ def init_caches(cfg: ModelConfig, batch: int, smax: int):
             else:
                 caches.append(
                     {
-                        "k": jnp.zeros((N, batch, W, kvh, hd), jnp.bfloat16),
-                        "v": jnp.zeros((N, batch, W, kvh, hd), jnp.bfloat16),
+                        "k": jnp.zeros((N, batch, W, kvh * hd), jnp.bfloat16),
+                        "v": jnp.zeros((N, batch, W, kvh * hd), jnp.bfloat16),
                     }
                 )
         else:

@@ -381,7 +381,7 @@ class ContinuousRolloutEngine:
             return (caches, cur_tok, cache_len, resp_len, done, budget,
                     out_tok, out_lp)
 
-        return jax.jit(refill)
+        return jax.jit(refill, donate_argnames=("caches", "out_tok", "out_lp"))
 
     def _make_continue(self, R: int, K: int, smax: int):
         """Resume ``R`` continuing episodes on free slots: scatter each
@@ -414,13 +414,15 @@ class ContinuousRolloutEngine:
             return (caches, cur_tok, cache_len, resp_len, done, budget,
                     out_tok, out_lp)
 
-        return jax.jit(cont)
+        return jax.jit(cont, donate_argnames=("caches", "out_tok", "out_lp"))
 
     def _make_burst(self, S: int):
         """The decode loop: a ``lax.while_loop`` stepping every slot, exiting
         as soon as (a) every slot is done — the early-exit on a drained
         queue — or (b) any slot *newly* finishes while prompts are pending,
-        handing control back to the host for an immediate refill."""
+        handing control back to the host for an immediate refill. The KV
+        arena stays in one buffer for the whole burst: each step writes one
+        row per slot and layer (``model.decode_step_sample``)."""
         model, temp, top_p = self.model, self.temperature, self.top_p
         eos, pad, max_new = self.eos_id, self.pad_id, self.max_new
         T = max_new - 1  # lockstep's decode-step count (key schedule length)
@@ -474,7 +476,9 @@ class ContinuousRolloutEngine:
                   out_tok, out_lp, t, occ)
             return jax.lax.while_loop(cond, body, st)
 
-        return jax.jit(burst)
+        # the arena and the output rows are updated in place: donated, a
+        # call neither copies nor duplicates them
+        return jax.jit(burst, donate_argnames=("caches", "out_tok", "out_lp"))
 
     # ------------------------------------------------------------------ #
     @staticmethod

@@ -194,6 +194,41 @@ def test_decode_attention_sliding_window():
     np.testing.assert_allclose(np.array(o_p), np.array(o_r), atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("S,lens,window", [
+    (256, [1, 97, 256], None),   # ragged kv_len: one slot, a partial tile, full
+    (160, [40, 159, 160], None),  # non-power-of-two arena width
+    (256, [60, 200, 256], 64),    # SWA window
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_stacked_arena_matches_per_layer(S, lens, window,
+                                                          dtype):
+    """The decode loop's stacked lane-folded arena (N, B, S, KVH*D), read at
+    layer n through the scalar-prefetched layer index, gives bitwise what
+    the per-layer call gives on ``arena[n]`` — with the index traced, as
+    the layer loop passes it."""
+    N, B, H, KVH, D = 3, 3, 4, 2, 32
+    k = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(k[0], (B, H, D), dtype)
+    ka = jax.random.normal(k[1], (N, B, S, KVH * D), dtype)
+    va = jax.random.normal(k[2], (N, B, S, KVH * D), dtype)
+    cl = jnp.asarray(lens, jnp.int32)
+    stacked = jax.jit(functools.partial(
+        da_pallas, window=window, block_s=64, interpret=True))
+    for n in range(N):
+        o_s, l_s = stacked(q, ka, va, cl, layer=jnp.int32(n))
+        o_l, l_l = da_pallas(q, ka[n].reshape(B, S, KVH, D),
+                             va[n].reshape(B, S, KVH, D), cl, window=window,
+                             block_s=64, interpret=True)
+        np.testing.assert_array_equal(np.asarray(o_s), np.asarray(o_l))
+        np.testing.assert_array_equal(np.asarray(l_s), np.asarray(l_l))
+    o_r, _ = ref.decode_attention(q, ka[1].reshape(B, S, KVH, D),
+                                  va[1].reshape(B, S, KVH, D), cl,
+                                  window=window, return_lse=True)
+    o_s, _ = stacked(q, ka, va, cl, layer=jnp.int32(1))
+    np.testing.assert_allclose(np.array(o_s, np.float32),
+                               np.array(o_r, np.float32), **tol(dtype))
+
+
 # --------------------------------------------------------------------------- #
 # paged decode attention (block-table gather through the serving page pool)
 # --------------------------------------------------------------------------- #

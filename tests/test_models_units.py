@@ -1,6 +1,7 @@
 """Model-layer unit tests: MoE dispatch equivalence, RoPE properties,
 causal conv, norms, tokenizer, pattern compression."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -174,3 +175,120 @@ def test_layer_scopes_name_every_matmul(arch):
         named = re.compile(rf"(^|[/(]){name}([/)]|$)")
         assert any(named.search(s) for s in decode), name
         assert any(named.search(s) and "transpose(" in s for s in grad), name
+
+
+# --------------------------------------------------------------------------- #
+# decode: the KV arena carried through the layer loop, written in place
+# --------------------------------------------------------------------------- #
+def _decode_cfg(window=None):
+    return reduced(ARCHS["qwen2.5-7b"], vocab_size=260, num_layers=3,
+                   sliding_window=window)
+
+
+def _masked_write_step(cfg, params, token, caches, cache_len, unroll=False):
+    """One decode step as the per-layer masked write computes it: the layer
+    scan slices each layer's cache out of the arena, writes the new position
+    through an elementwise select over the whole (B, W) cache, attends over
+    that layer's cache and restacks the layers."""
+    from repro.kernels import ref
+    from repro.models import layers
+
+    B, hd = token.shape[0], cfg.head_dim
+    W = caches[0]["k"].shape[2]
+    ring = cfg.sliding_window is not None and W <= cfg.sliding_window
+    slot = cache_len % W if ring else cache_len
+    sel = (jnp.arange(W)[None, :] == slot[:, None])[..., None]
+    if ring:
+        lens, window = jnp.minimum(cache_len + 1, W), None
+    else:
+        lens, window = cache_len + 1, cfg.sliding_window
+
+    def block(h, xs):
+        p, c = xs
+        hn = layers.apply_norm(cfg, p["norm1"], h)
+        q, k, v = layers.qkv_proj(cfg, p["attn"], hn, cache_len[:, None])
+        kc = jnp.where(sel, k[:, 0].reshape(B, 1, -1), c["k"])
+        vc = jnp.where(sel, v[:, 0].reshape(B, 1, -1), c["v"])
+        o = ref.decode_attention(q[:, 0], kc.reshape(B, W, -1, hd),
+                                 vc.reshape(B, W, -1, hd), lens,
+                                 window=window)
+        h = h + layers.out_proj(cfg, p["attn"], o)[:, None]
+        h = h + layers.apply_mlp(cfg, p["mlp"],
+                                 layers.apply_norm(cfg, p["norm2"], h))
+        return h, {"k": kc, "v": vc}
+
+    h = lm.embed_tokens(cfg, params, token[:, None])
+    xs = (params["blocks"][0], caches[0])
+    if unroll:
+        ys = []
+        for n in range(cfg.num_layers):
+            h, y = block(h, jax.tree.map(lambda a: a[n], xs))
+            ys.append(y)
+        new = jax.tree.map(lambda *a: jnp.stack(a), *ys)
+    else:
+        h, new = jax.lax.scan(block, h, xs)
+    h = layers.apply_norm(cfg, params["final_norm"], h)
+    return lm._head_logits(cfg, params, h[:, 0]), [new]
+
+
+@pytest.mark.parametrize("window", [None, 8])
+@pytest.mark.parametrize("unroll", [False, True])
+def test_carried_arena_decode_equals_masked_write_step(window, unroll):
+    """The carried-arena decode step (one row per sequence and layer written
+    in place, the kernel dispatch reading layer n of the stacked arena) is
+    bitwise the per-layer masked-write step, over ragged cache lengths and,
+    with a window of 8 under an arena of 20, an SWA ring that wraps."""
+    cfg = _decode_cfg(window)
+    params = lm.init(cfg, jax.random.PRNGKey(0))
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (3, 6), 3, 200)
+    _, caches, cache_len = lm.prefill(cfg, params, prompt, smax=20)
+    cache_len = cache_len - jnp.asarray([0, 2, 5], jnp.int32)  # ragged
+    token = jnp.asarray([7, 9, 11], jnp.int32)
+    step = jax.jit(functools.partial(lm.decode_step, cfg, unroll=unroll))
+    masked = jax.jit(functools.partial(_masked_write_step, cfg,
+                                       unroll=unroll))
+    want_caches = caches
+    for _ in range(12):  # past the ring's width when windowed
+        logits, caches, next_len = step(params, token, caches, cache_len)
+        want, want_caches = masked(params, token, want_caches, cache_len)
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+        for got_c, want_c in zip(caches, want_caches):
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(np.asarray(got_c[name]),
+                                              np.asarray(want_c[name]))
+        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        cache_len = next_len
+
+
+def _arena_selects(jaxpr, arena_shapes):
+    """``select_n`` equations anywhere in ``jaxpr`` (sub-jaxprs included)
+    whose output has one of ``arena_shapes``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "select_n" and any(
+                tuple(v.aval.shape) in arena_shapes for v in eqn.outvars):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _arena_selects(sub, arena_shapes)
+    return found
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_decode_writes_the_arena_without_a_select(window):
+    """No select with the arena's shape, or one layer's, is left in the
+    decode step: a masked write over the whole cache would read and write
+    the arena once per layer and step."""
+    cfg = _decode_cfg(window)
+    params = lm.init(cfg, jax.random.PRNGKey(0))
+    caches = lm.init_caches(cfg, 4, 20)
+    arena = caches[0]["k"].shape
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c, n, k: lm.decode_step_sample(cfg, p, t, c, n, k, 1.0)
+    )(params, jnp.ones((4,), jnp.int32), caches,
+      jnp.full((4,), 6, jnp.int32), jax.random.PRNGKey(1))
+    assert not _arena_selects(jaxpr.jaxpr, {arena, arena[1:]})
+    # the check sees a masked write where there is one
+    masked = jax.make_jaxpr(lambda t, c, n: _masked_write_step(
+        cfg, params, t, c, n))(jnp.ones((4,), jnp.int32), caches,
+                               jnp.full((4,), 6, jnp.int32))
+    assert _arena_selects(masked.jaxpr, {arena, arena[1:]})

@@ -194,3 +194,62 @@ def test_grpo_pipeline_runs_on_multi_device_mesh():
         print('OK', pipe.buffer.stats)
     """)
     assert "OK" in out
+
+
+def test_model_sharded_arena_keeps_the_masked_write():
+    """Where the arena's W axis is sharded over a `model` axis of size > 1,
+    the decode step writes the new position through an elementwise select
+    (a scatter at a traced position made GSPMD all-gather the cache); on a
+    batch-sharded (4, 1) mesh it writes one row per sequence in place, and
+    no arena-shaped all-gather appears. Both match the step on one device
+    (float32 weights, so that the `model` axis's partial sums differ from
+    it by rounding alone)."""
+    out = run_py("""
+        import re
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import ARCHS, reduced
+        from repro.models import lm
+        cfg = reduced(ARCHS['qwen2.5-7b'], vocab_size=260, num_layers=2)
+        params = jax.tree.map(lambda a: a.astype(jnp.float32),
+                              lm.init(cfg, jax.random.PRNGKey(0)))
+        prompt = jax.random.randint(jax.random.PRNGKey(1), (4, 6), 3, 200)
+        _, caches, n = lm.prefill(cfg, params, prompt, smax=16)
+        n = n - jnp.asarray([0, 1, 3, 5], jnp.int32)
+        tok = jnp.asarray([5, 6, 7, 8], jnp.int32)
+        step = lambda p, t, c, n: lm.decode_step(cfg, p, t, c, n)
+        want, want_caches, _ = jax.jit(step)(params, tok, caches, n)
+        arena = caches[0]['k'].shape
+
+        def arena_selects(jaxpr):
+            found = 0
+            for eqn in jaxpr.eqns:
+                found += eqn.primitive.name == 'select_n' and any(
+                    tuple(v.aval.shape) in (arena, arena[1:])
+                    for v in eqn.outvars)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    found += arena_selects(sub)
+            return found
+
+        assert arena_selects(jax.make_jaxpr(step)(params, tok, caches, n).jaxpr) == 0
+        dims = ','.join(map(str, arena[2:]))
+        for shape, masked in (((1, 4), True), ((4, 1), False)):
+            mesh = make_compat_mesh(shape, ('data', 'model'),
+                                    devices=jax.devices()[:4])
+            with use_mesh(mesh):
+                jaxpr = jax.make_jaxpr(step)(params, tok, caches, n)
+                fn = jax.jit(step)
+                hlo = fn.lower(params, tok, caches, n).compile().as_text()
+                got, got_caches, _ = fn(params, tok, caches, n)
+            assert (arena_selects(jaxpr.jaxpr) > 0) == masked, shape
+            if not masked:
+                assert not re.search(rf'f32\\[[0-9,]*{dims}\\]\\S* all-gather', hlo)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-5, rtol=1e-5)
+            for g, w in zip(got_caches, want_caches):
+                for name in ('k', 'v'):
+                    np.testing.assert_allclose(np.asarray(g[name]),
+                                               np.asarray(w[name]),
+                                               atol=1e-5, rtol=1e-5)
+        print('OK')
+    """)
+    assert "OK" in out

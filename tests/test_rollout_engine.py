@@ -90,6 +90,61 @@ def test_token_identical_greedy(tiny_model):
     np.testing.assert_array_equal(np.asarray(got.tokens), np.asarray(ref.tokens))
 
 
+def _swa_model(window=8):
+    cfg = reduced(ARCHS["qwen2.5-7b"], vocab_size=260, num_layers=2,
+                  sliding_window=window)
+    model = get_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+def test_token_identical_to_lockstep_swa_ring():
+    """A sliding window of 8 under an 18-wide arena: each slot's ring wraps
+    and every decode step writes its ring slot in place; the engine stays
+    token-for-token with lockstep."""
+    cfg, model, params = _swa_model()
+    B, Lp, T = 8, 6, 12
+    prompt = _prompts(B, Lp, seed=3)
+    key = jax.random.PRNGKey(6)
+    ref = generate(model, params, prompt, key, max_new=T, temperature=2.0,
+                   eos_id=129, pad_id=0)
+    eng = ContinuousRolloutEngine(model, max_new=T, temperature=2.0,
+                                  eos_id=129, pad_id=0)
+    got = eng(params, prompt, key)
+    np.testing.assert_array_equal(np.asarray(got.tokens), np.asarray(ref.tokens))
+    np.testing.assert_array_equal(
+        np.asarray(got.lengths), np.asarray(ref.lengths))
+    np.testing.assert_allclose(
+        np.asarray(got.old_logprob), np.asarray(ref.old_logprob), atol=5e-3)
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_multi_turn_continuation_resumes_from_the_arena(window):
+    """Multi-turn episodes through a 3-slot pool: each continuation gathers
+    its rows from the in-place arena, feeds the observation through the
+    decode step and scatters the rows back. The assembled sequences agree
+    with a full forward at every action position, windowed or not."""
+    from repro.configs import EnvConfig
+    from repro.data.tokenizer import ByteTokenizer
+    from repro.rl import envs
+    from repro.rl.reward import make_math_prompts
+
+    tok = ByteTokenizer()
+    cfg, model, params = _swa_model(window)
+    prompts, _ = make_math_prompts(np.random.default_rng(5), 6, tok)
+    env_cfg = EnvConfig(name="dialog", max_turns=3, obs_budget=8)
+    runtime = envs.EnvRuntime(envs.get_env("dialog"), env_cfg, tok)
+    eng = ContinuousRolloutEngine(
+        model, max_new=8, temperature=2.0, eos_id=tok.eos_id, pad_id=0,
+        num_slots=3, env=runtime, max_turns=3, turn_budget=4, obs_budget=8)
+    got = eng(params, jnp.asarray(prompts), jax.random.PRNGKey(8))
+    assert eng.last_stats["cont_refills"] >= 1
+    lp, _ = model.logprobs(params, got.tokens)
+    m = np.asarray(got.response_mask)
+    assert m.sum() > 0
+    np.testing.assert_allclose(
+        np.asarray(got.old_logprob)[m], np.asarray(lp)[m], atol=5e-2)
+
+
 # --------------------------------------------------------------------------- #
 # slot refill / early exit
 # --------------------------------------------------------------------------- #

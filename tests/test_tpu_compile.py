@@ -93,6 +93,13 @@ KERNELS = {
         [((B_DEC, H, D), bf16), ((B_DEC, S, KVH, D), bf16),
          ((B_DEC, S, KVH, D), bf16), ((B_DEC,), i32)],
     ),
+    # the decode loop's stacked lane-folded arena (2 layers), read at a
+    # scalar-prefetched layer index
+    "decode_arena": (
+        lambda q, k, v, n, i: decode_attention(q, k, v, n, layer=i),
+        [((B_DEC, H, D), bf16), ((2, B_DEC, S, KVH * D), bf16),
+         ((2, B_DEC, S, KVH * D), bf16), ((B_DEC,), i32), ((), i32)],
+    ),
     "decode_int8": (
         lambda q, k, v, ks, vs, n: decode_attention_quant(q, k, v, ks, vs, n),
         [((B_DEC, H, D), bf16), ((B_DEC, S, KVH, D), i8),
@@ -185,19 +192,24 @@ def _flash_step(q, k, v):
     return jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))(q, k, v)
 
 
-# (fn, [(shape, dtype, split over data?)])
+# (fn, [(shape, dtype, axis split over data, or None)])
 PARTITIONED = {
-    "flash_attention": (_flash_step, [((8, 256, H, D), bf16, True),
-                                      ((8, 256, KVH, D), bf16, True),
-                                      ((8, 256, KVH, D), bf16, True)]),
+    "flash_attention": (_flash_step, [((8, 256, H, D), bf16, 0),
+                                      ((8, 256, KVH, D), bf16, 0),
+                                      ((8, 256, KVH, D), bf16, 0)]),
     "decode_attention": (
         lambda q, k, v, n: ops.decode_attention(q, k, v, n),
-        [((B_DEC, H, D), bf16, True), ((B_DEC, S, KVH, D), bf16, True),
-         ((B_DEC, S, KVH, D), bf16, True), ((B_DEC,), i32, True)]),
+        [((B_DEC, H, D), bf16, 0), ((B_DEC, S, KVH, D), bf16, 0),
+         ((B_DEC, S, KVH, D), bf16, 0), ((B_DEC,), i32, 0)]),
+    "decode_attention_arena": (
+        lambda q, k, v, n, i: ops.decode_attention(q, k, v, n, layer=i),
+        [((B_DEC, H, D), bf16, 0), ((2, B_DEC, S, KVH * D), bf16, 1),
+         ((2, B_DEC, S, KVH * D), bf16, 1), ((B_DEC,), i32, 0),
+         ((), i32, None)]),
     "fused_sample": (
         lambda h, w: ops.fused_sample(h, w, jax.random.PRNGKey(0), 1.0,
                                       vocab_size=19_008),
-        [((B_DEC, D_MODEL), bf16, True), ((D_MODEL, VOCAB_PAD), bf16, False)]),
+        [((B_DEC, D_MODEL), bf16, 0), ((D_MODEL, VOCAB_PAD), bf16, None)]),
 }
 
 
@@ -211,9 +223,9 @@ def test_kernels_partition_over_four_chips(four_chips, name):
     from repro.utils.jax_compat import use_mesh
 
     fn, shapes = PARTITIONED[name]
-    args = [jax.ShapeDtypeStruct(
-        s, dt, sharding=NamedSharding(four_chips, P("data") if split else P()))
-        for s, dt, split in shapes]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=NamedSharding(
+        four_chips, P() if axis is None else P(*[None] * axis, "data")))
+        for s, dt, axis in shapes]
     ops.set_mode("pallas")
     try:
         with use_mesh(four_chips):
@@ -221,3 +233,57 @@ def test_kernels_partition_over_four_chips(four_chips, name):
     finally:
         ops.set_mode(None)
     assert "tpu_custom_call" in hlo
+
+
+def test_decode_burst_keeps_the_arena_in_place(one_chip):
+    """The continuous engine's decode burst at the benchmark cell's size
+    (qwen2.5-7b widths, 2 layers, 8 slots, a 1280-wide arena): the KV arena
+    and the output rows are donated and aliased to the outputs, and no op
+    copies, relayouts, selects over or restacks the arena or one of its
+    layers — each step writes one row per slot and layer with a scatter."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import get_model
+    from repro.rl.rollout_engine import ContinuousRolloutEngine
+
+    cfg = dataclasses.replace(get_config("qwen2.5-7b"), num_layers=2,
+                              vocab_size=19_008)
+    model = get_model(cfg)
+    slots, max_new, smax = 8, 1024, 1280
+    burst = ContinuousRolloutEngine(
+        model, max_new=max_new, eos_id=2)._make_burst(slots)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    caches = shaped(jax.eval_shape(lambda: model.init_caches(slots, smax)))
+    out_rows = (arg((slots, max_new), i32), arg((slots, max_new), f32))
+    args = (shaped(jax.eval_shape(model.init, jax.random.PRNGKey(0))), caches,
+            arg((slots,), i32), arg((slots,), i32), arg((slots,), i32),
+            arg((slots,), jnp.bool_), arg((slots,), i32), *out_rows,
+            arg((), i32), arg((), i32), arg((max_new - 1, 2), jnp.uint32),
+            arg((2,), jnp.uint32), arg((), jnp.bool_))
+    ops.set_mode("pallas")
+    try:
+        compiled = burst.lower(*args).compile()
+    finally:
+        ops.set_mode(None)
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((caches, out_rows)))
+    assert compiled.memory_analysis().alias_size_in_bytes == donated
+    arena = caches[0]["k"].shape  # (2, 8, 1280, 512): lane-folded
+    assert arena == (2, slots, smax, KVH * D)
+    dims = ",".join(map(str, arena))
+    touch = re.compile(
+        rf"= bf16\[({dims}|{dims.split(',', 1)[1]})\]\S* "
+        r"(copy|copy-start|copy-done|reshape|transpose|select|"
+        r"dynamic-update-slice|dynamic-slice|concatenate)\(")
+    hlo = compiled.as_text()
+    assert not [l for l in hlo.splitlines() if touch.search(l)]
+    assert len(re.findall(rf"= bf16\[{dims}\]\S* scatter\(", hlo)) == 2
