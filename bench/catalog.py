@@ -1,11 +1,13 @@
 """Finds the benchmark's parts by name: a cell in ``workloads/<name>.json``,
-a configuration in ``configs/<name>.json``, a traffic mix in
+a configuration in ``configs/<name>.json``, the reference and FLOP count of
+its layout's kind in ``models/<kind>.py``, a traffic mix in
 ``traffic/<name>.json`` and its generator in ``traffic/<generator>.py``, a
 per-layer metric's reader in ``metrics/<name>.py``, and the chip peaks in
 ``peaks.json``. Adding any of them is adding a file; nothing here changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -58,6 +60,23 @@ def load_traffic(name: str, root: pathlib.Path = ROOT) -> dict:
 
 def traffic_generator(mix: dict, root: pathlib.Path = ROOT) -> ModuleType:
     return _module(root / "traffic" / f"{_checked(mix['generator'])}.py")
+
+
+def model(kind: str, root: pathlib.Path = ROOT) -> ModuleType:
+    """The model of one layout kind: the functions ``models/__init__.py``
+    names. A kind with no file of its own is an error, never a default."""
+    path = root / "models" / f"{_checked(kind)}.py"
+    if kind.startswith("_") or not path.is_file():
+        raise LookupError(f"no model kind {kind!r}: {path} is not a kind's "
+                          "file")
+    return _kind_module(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_module(path: pathlib.Path) -> ModuleType:
+    """A kind's file, imported once per path: the reference and the FLOP
+    count ask for it on every call."""
+    return _module(path)
 
 
 def metric_readers(root: pathlib.Path = ROOT) -> Dict[str, ModuleType]:

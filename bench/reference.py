@@ -1,4 +1,4 @@
-"""Plain float32 reference of the benchmark's models and of one GRPO step.
+"""Plain float32 reference of one GRPO step on the benchmark's models.
 
 It imports nothing of the program and takes nothing the program has made.
 It builds its own weights from the seed, following the initialisation the
@@ -6,8 +6,10 @@ configuration file's ``layout`` states, and reads only the token ids, masks
 and answers of the rollouts. Then, in float32 at the highest matmul
 precision, it computes:
 
-- per-token log-probabilities and entropies (dense attention blocks, or
-  Mamba2 SSD blocks in their chunked dual form);
+- per-token log-probabilities and entropies: the hidden states come from
+  the model of the layout's kind (``bench/models/<kind>.py``, found by
+  :func:`bench.catalog.model`); the head and its statistics are computed
+  here, in chunks of positions;
 - the reward of the synthetic math task, GRPO's group-relative advantages,
   and the GRPO loss (clipped surrogate, k3 KL to the frozen reference,
   entropy bonus);
@@ -26,115 +28,37 @@ that it fits one chip after the program's state is freed.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
-F8 = jnp.float8_e4m3fn
-F8_MAX = 448.0
+from bench import catalog
+from bench.models._common import _mm
+
 HEAD_CHUNK = 256
 ROWS = 1  # rows in one block of the forward and of the gradient
-SSD_CHUNK = 128
 NEG = -1e30  # logit of a padded vocabulary slot: weight 0, finite products
 
 
 # --------------------------------------------------------------------------- #
-# shapes
+# the model, from the file its layout's kind names
 # --------------------------------------------------------------------------- #
 def arch_of(config: dict) -> dict:
-    """The sizes the reference needs, from a configuration file."""
+    """The sizes the reference needs, from a configuration file: those every
+    kind has, and those of the kind its ``layout.kind`` names."""
     lay = config["layout"]
-    a = dict(kind=lay["kind"], layers=lay["num_layers"], d=lay["d_model"],
-             vocab=lay["vocab_size"], vocab_padded=lay["padded_vocab"],
-             tied=lay["tie_embeddings"], eps=lay["norm_eps"])
-    if a["kind"] == "dense":
-        a.update(heads=lay["num_heads"], heads_padded=lay["padded_heads"],
-                 kv_heads=lay["num_kv_heads"], head_dim=lay["head_dim"],
-                 d_ff=lay["d_ff"], rope_theta=lay["rope_theta"])
-    else:
-        a.update(d_inner=lay["ssm_expand"] * lay["d_model"],
-                 state=lay["ssm_state"], ssm_head_dim=lay["ssm_headdim"],
-                 groups=lay["ssm_ngroups"], conv=lay["ssm_conv"])
-        a["ssm_heads"] = a["d_inner"] // a["ssm_head_dim"]
-    return a
-
-
-# --------------------------------------------------------------------------- #
-# initialisation (the layout's stated scheme, from the seed)
-# --------------------------------------------------------------------------- #
-def _normal(key, shape, scale):
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(
-        jnp.bfloat16)
-
-
-def _dense(key, shape):
-    return _normal(key, shape, 1.0 / shape[0] ** 0.5)
-
-
-def _init_attention(a, key):
-    d, hd, hp, kvh = a["d"], a["head_dim"], a["heads_padded"], a["kv_heads"]
-    ks = jax.random.split(key, 4)
-    real = (jnp.arange(hp * hd) < a["heads"] * hd).astype(jnp.bfloat16)
-    return {"w_q": _dense(ks[0], (d, hp * hd)) * real[None, :],
-            "w_k": _dense(ks[1], (d, kvh * hd)),
-            "w_v": _dense(ks[2], (d, kvh * hd)),
-            "w_o": _dense(ks[3], (hp * hd, d)) * real[:, None]}
-
-
-def _init_mlp(a, key):
-    ks = jax.random.split(key, 3)
-    d, f = a["d"], a["d_ff"]
-    return {"w_in": _dense(ks[0], (d, f)), "w_out": _dense(ks[1], (f, d)),
-            "w_gate": _dense(ks[2], (d, f))}
-
-
-def _init_ssm(a, key):
-    d, din, n, nh = a["d"], a["d_inner"], a["state"], a["ssm_heads"]
-    g, kw = a["groups"], a["conv"]
-    ks = jax.random.split(key, 8)
-    s = 1.0 / d ** 0.5
-    dt = jnp.exp(jax.random.uniform(ks[6], (nh,), jnp.float32)
-                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
-    return {"w_z": _normal(ks[0], (d, din), s),
-            "w_x": _normal(ks[1], (d, din), s),
-            "w_B": _normal(ks[2], (d, g * n), s),
-            "w_C": _normal(ks[3], (d, g * n), s),
-            "w_dt": _normal(ks[4], (d, nh), s),
-            "conv_x": _normal(ks[5], (kw, din), 1.0 / kw),
-            "conv_bc": _normal(ks[7], (kw, 2 * g * n), 1.0 / kw),
-            "A_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
-            "D": jnp.ones((nh,), jnp.float32),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-            "norm_w": jnp.zeros((din,), jnp.float32),
-            "w_out": _normal(jax.random.fold_in(key, 99), (din, d),
-                             1.0 / din ** 0.5)}
-
-
-def _init_block(a, key):
-    ks = jax.random.split(key, 4)
-    zeros = {"w": jnp.zeros((a["d"],), jnp.float32)}
-    if a["kind"] == "dense":
-        return {"norm1": zeros, "attn": _init_attention(a, ks[0]),
-                "norm2": dict(zeros), "mlp": _init_mlp(a, ks[1])}
-    return {"norm1": zeros, "ssm": _init_ssm(a, ks[0])}
+    return dict(kind=lay["kind"], layers=lay["num_layers"], d=lay["d_model"],
+                vocab=lay["vocab_size"], vocab_padded=lay["padded_vocab"],
+                tied=lay["tie_embeddings"], eps=lay["norm_eps"],
+                **catalog.model(lay["kind"]).arch(lay))
 
 
 @functools.partial(jax.jit, static_argnums=0)
 def _init(a_items, key):
     a = dict(a_items)
-    ks = jax.random.split(key, 3)  # one repeating layer kind: pattern of 1
-    blocks = jax.vmap(lambda k: _init_block(a, k))(
-        jax.random.split(ks[0], a["layers"]))
-    v, d = a["vocab_padded"], a["d"]
-    p = {"embed": _normal(ks[1], (v, d), 0.02), "blocks": [blocks],
-         "final_norm": {"w": jnp.zeros((d,), jnp.float32)}}
-    if not a["tied"]:
-        p["lm_head"] = _normal(ks[2], (d, v), 1.0 / d ** 0.5)
-    return p
+    return catalog.model(a["kind"]).init(a, key)
 
 
 def init_params(a: dict, seed: int):
@@ -144,142 +68,10 @@ def init_params(a: dict, seed: int):
     return _init(tuple(sorted(a.items())), actor_key)
 
 
-# --------------------------------------------------------------------------- #
-# float32 (or float8-operand) arithmetic
-# --------------------------------------------------------------------------- #
-def _q(x, precision):
-    """An operand as the matmul reads it: float32, or rounded to float8 with
-    one scale per tensor (the gradient passes through the rounding)."""
-    x = x.astype(jnp.float32)
-    if precision == "f32":
-        return x
-    scale = jax.lax.stop_gradient(
-        jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX)
-    rounded = (x / scale).astype(F8).astype(jnp.float32) * scale
-    return x + jax.lax.stop_gradient(rounded - x)
-
-
-def _mm(x, w, precision):
-    return jnp.matmul(_q(x, precision), _q(w, precision), precision=HIGHEST)
-
-
-def _einsum(spec, x, y, precision):
-    return jnp.einsum(spec, _q(x, precision), _q(y, precision),
-                      precision=HIGHEST)
-
-
-def _rmsnorm(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (
-        1.0 + w.astype(jnp.float32))
-
-
-def _rope(x, theta):
-    """x (b, S, H, D), positions 0..S-1, halves rotated."""
-    S, D = x.shape[1], x.shape[-1]
-    half = D // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(a, p, h, pr):
-    b, S, _ = h.shape
-    hp, kvh, hd = a["heads_padded"], a["kv_heads"], a["head_dim"]
-    q = _rope(_mm(h, p["w_q"], pr).reshape(b, S, hp, hd), a["rope_theta"])
-    k = _rope(_mm(h, p["w_k"], pr).reshape(b, S, kvh, hd), a["rope_theta"])
-    v = _mm(h, p["w_v"], pr).reshape(b, S, kvh, hd)
-    q = q.reshape(b, S, kvh, hp // kvh, hd)  # query head i reads kv head i // g
-    s = _einsum("bqhgd,bkhd->bhgqk", q, k, pr) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    o = _einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(s, -1), v, pr)
-    return _mm(o.reshape(b, S, hp * hd), p["w_o"], pr)
-
-
-def _mlp(p, h, pr):
-    return _mm(jax.nn.silu(_mm(h, p["w_gate"], pr)) * _mm(h, p["w_in"], pr),
-               p["w_out"], pr)
-
-
-def _causal_conv(x, w):
-    K, S = w.shape[0], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return sum(xp[:, i:i + S] * w[i].astype(jnp.float32) for i in range(K))
-
-
-def _ssd(x, dt, A, Bm, Cm):
-    """Chunked dual form of h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t^T,
-    y_t = C_t . h_t. x (b,S,H,P); dt (b,S,H); B, C (b,S,H,N)."""
-    b, S, H, P = x.shape
-    T = min(SSD_CHUNK, S)
-    nc = S // T
-    ch = lambda t: t.reshape(b, nc, T, *t.shape[2:])
-    xc, dtc, Bc, Cc = ch(x), ch(dt), ch(Bm), ch(Cm)
-    cs = jnp.cumsum(dtc * A, axis=2)  # (b, nc, T, H) log-decay from chunk start
-    diff = cs[:, :, :, None, :] - cs[:, :, None, :, :]  # (b,nc,i,j,H)
-    lower = jnp.tril(jnp.ones((T, T), bool))[None, None, :, :, None]
-    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))
-    cb = jnp.einsum("bcihn,bcjhn->bcijh", Cc, Bc, precision=HIGHEST)
-    y = jnp.einsum("bcijh,bcjhp->bcihp", cb * decay, dtc[..., None] * xc,
-                   precision=HIGHEST)
-    to_end = jnp.exp(cs[:, :, -1:, :] - cs)  # (b, nc, T, H)
-    states = jnp.einsum("bcjhn,bcjhp->bchpn", Bc * (dtc * to_end)[..., None],
-                        xc, precision=HIGHEST)
-    chunk_decay = jnp.exp(cs[:, :, -1, :])  # (b, nc, H)
-
-    def carry(hprev, inp):
-        st, dec = inp
-        return dec[..., None, None] * hprev + st, hprev
-
-    _, h_in = jax.lax.scan(carry, jnp.zeros((b, H, P, Bm.shape[-1])),
-                           (jnp.moveaxis(states, 1, 0),
-                            jnp.moveaxis(chunk_decay, 1, 0)))
-    h_in = jnp.moveaxis(h_in, 0, 1)  # state entering each chunk
-    y = y + jnp.einsum("bcihn,bchpn->bcihp", Cc * jnp.exp(cs)[..., None],
-                       h_in, precision=HIGHEST)
-    return y.reshape(b, S, H, P)
-
-
-def _ssm(a, p, h, pr):
-    b, S, _ = h.shape
-    din, n, nh, g = a["d_inner"], a["state"], a["ssm_heads"], a["groups"]
-    z = _mm(h, p["w_z"], pr)
-    x = _causal_conv(_mm(h, p["w_x"], pr), p["conv_x"])
-    bc = jnp.concatenate([_mm(h, p["w_B"], pr), _mm(h, p["w_C"], pr)], -1)
-    bc = jax.nn.silu(_causal_conv(bc, p["conv_bc"]))
-    x = jax.nn.silu(x)
-    dt = jax.nn.softplus(_mm(h, p["w_dt"], pr) + p["dt_bias"])
-    rep = lambda t: jnp.repeat(t.reshape(b, S, g, n), nh // g, axis=2)
-    Bm, Cm = rep(bc[..., :g * n]), rep(bc[..., g * n:])
-    xh = x.reshape(b, S, nh, a["ssm_head_dim"])
-    y = _ssd(xh, dt, -jnp.exp(p["A_log"]), Bm, Cm) + p["D"][:, None] * xh
-    y = _rmsnorm(y.reshape(b, S, din) * jax.nn.silu(z), p["norm_w"], a["eps"])
-    return _mm(y, p["w_out"], pr)
-
-
-def _hidden(a, params, tokens, pr):
-    h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-    @jax.checkpoint
-    def layer(h, p):
-        if a["kind"] == "dense":
-            h = h + _attention(a, p["attn"], _rmsnorm(h, p["norm1"]["w"],
-                                                     a["eps"]), pr)
-            return h + _mlp(p["mlp"], _rmsnorm(h, p["norm2"]["w"], a["eps"]),
-                            pr), None
-        return h + _ssm(a, p["ssm"], _rmsnorm(h, p["norm1"]["w"], a["eps"]),
-                        pr), None
-
-    h, _ = jax.lax.scan(layer, h, params["blocks"][0])
-    return _rmsnorm(h, params["final_norm"]["w"], a["eps"])
-
-
 def _token_stats(a, params, tokens, pr):
     """(log-prob, entropy), each (b, S): position i scores tokens[:, i] given
     the tokens before it; position 0 reads 0."""
-    h = _hidden(a, params, tokens, pr)
+    h = catalog.model(a["kind"]).hidden(a, params, tokens, pr)
     head = params["embed"].T if a["tied"] else params["lm_head"]
     real = jnp.arange(a["vocab_padded"]) < a["vocab"]
     b, S, d = h.shape
