@@ -51,6 +51,7 @@ class Traffic:
         mix = cell["traffic_spec"]
         gen = catalog.traffic_generator(mix)
         self.group = mix["group_size"]
+        self.cycle = gen.cycle(mix)  # iterations in a whole cycle of sizes
         self.prompts_per_iter = cell["prompts_per_iter"]
         self._batches = gen.batches(
             mix, seed=seed, prompts_per_iter=self.prompts_per_iter,
@@ -208,6 +209,29 @@ class CompileCounter:
             self.count += 1
 
 
+class GcPauses:
+    """Python's collector pauses while entered, through ``gc.callbacks``:
+    ``pauses`` holds ``(generation, start, seconds)`` for each."""
+
+    def __init__(self):
+        self.pauses: List[tuple] = []
+        self._start = 0.0
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._start = time.perf_counter()
+        else:
+            self.pauses.append((info["generation"], self._start,
+                                time.perf_counter() - self._start))
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+
 def prepare(require_tpu: bool, chips: int):
     """Compile cache on, devices checked; returns (devices, peaks)."""
     import jax
@@ -272,28 +296,38 @@ class Session:
         jax.block_until_ready(pipe.ctx.actor_state.params)
 
     def window(self, seconds: float, iterations: Optional[int] = None):
-        """Whole iterations until ``seconds`` have passed (or exactly
+        """Whole iterations until ``seconds`` have passed and the window
+        holds whole cycles of the traffic's sizes (or exactly
         ``iterations``), the last one's update waited for. Returns the
-        window's length in seconds and its counts."""
+        window's length in seconds and its counts, with each iteration's
+        host time, tokens and the collector's pauses inside it."""
         import jax
 
-        pipe, rec = self.pipe, self.rec
+        pipe, rec, cycle = self.pipe, self.rec, self.traffic.cycle
         counter = CompileCounter()
         rec.reset_counts()
         counter.on = True
-        iters = 0
+        ends, tokens = [], []
         w0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("bench.window"):
+        with GcPauses() as pauses, jax.profiler.TraceAnnotation(
+                "bench.window"):
             while True:
                 pipe.run(1)
-                iters += 1
-                if (iters >= iterations if iterations
-                        else time.perf_counter() - w0 >= seconds):
+                ends.append(time.perf_counter())
+                tokens.append(rec.prompt_tokens + rec.response_tokens)
+                iters = len(ends)
+                if (iters >= iterations if iterations else
+                        ends[-1] - w0 >= seconds and iters % cycle == 0):
                     break
             jax.block_until_ready(pipe.ctx.actor_state.params)
         window_s = time.perf_counter() - w0
         counter.on = False
-        return window_s, window_counts(rec, iters, counter.count)
+        counts = window_counts(rec, iters, counter.count)
+        counts["iteration_s"] = np.diff([w0] + ends).tolist()
+        counts["iteration_tokens"] = np.diff([0] + tokens).tolist()
+        counts["gc_pauses"] = [(int(np.searchsorted(ends, t0)), gen, dt)
+                               for gen, t0, dt in pauses.pauses]
+        return window_s, counts
 
     def step_scopes(self) -> Dict[str, Dict[str, str]]:
         """The actor step's instructions and the JAX scopes they came from,
@@ -352,6 +386,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     checks = check_against_reference(cell, seed, sess.rec.steps,
                                      sess.grad_norms, sess.delta_norms,
                                      sess.shapes)
+    print_iterations(counts)
     print(f"bench: setup {setup_s:.1f} s, window {window_s:.1f} s "
           f"({counts['iterations']} iterations), reference "
           f"{time.perf_counter() - r0:.1f} s", file=sys.stderr)
@@ -379,6 +414,24 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     result["checks"] = {k: {"value": c["value"], "limit": c["limit"]}
                         for k, c in checks.items()}
     return result
+
+
+def print_iterations(counts: dict, stream=sys.stderr):
+    """Each window iteration's host time, tokens and collector pauses, and
+    the pauses by generation: the diagnostics of a window's steadiness."""
+    gc_s = collections.defaultdict(float)
+    by_gen = collections.defaultdict(lambda: [0, 0.0])
+    for i, gen, dt in counts["gc_pauses"]:
+        gc_s[i] += dt
+        by_gen[gen][0] += 1
+        by_gen[gen][1] += dt
+    for i, (dt, tok) in enumerate(zip(counts["iteration_s"],
+                                      counts["iteration_tokens"])):
+        print(f"bench: iteration {i}: {dt:.6f} s, {tok} tokens, "
+              f"gc {gc_s[i]:.6f} s", file=stream)
+    print("bench: gc pauses in the window: " + (", ".join(
+        f"generation {g} {n} for {t:.6f} s"
+        for g, (n, t) in sorted(by_gen.items())) or "none"), file=stream)
 
 
 def window_counts(rec: Recorder, iters: int, compiles: int) -> dict:

@@ -17,6 +17,10 @@ distributions, with ``v_i`` the van der Corput sequence, so any ``2**k``
 consecutive iterations cover the mixture evenly and the amount of work in a
 window does not depend on the seed. The seed permutes the sizes among the
 rows and draws the token ids and answers.
+
+The harness reads two functions of a generator: ``batches``, the stream of
+iterations, and ``cycle``, the number of iterations a measured window
+holds a whole multiple of.
 """
 from __future__ import annotations
 
@@ -79,6 +83,17 @@ def budgets_at(u: np.ndarray, mix) -> np.ndarray:
         within = (u[sel] - edges[k]) / share
         out[sel] = np.round(lo + within * (hi - lo))
     return out
+
+
+def cycle(mix: dict) -> int:
+    """Iterations in one whole cycle of the traffic's sizes: 4, whatever the
+    mix. Any 4 consecutive van der Corput points fall one in each quarter of
+    [0, 1) (the two lowest bits of ``i + 1`` take all four values and set
+    the point's first two binary digits), so any 4 consecutive iterations,
+    from any start, draw their ``n`` lengths at levels one in each of the
+    ``4 * n`` strata of 1/(4·n): a window of whole cycles holds the mixture
+    evenly, wherever it starts."""
+    return 4
 
 
 def batches(mix: dict, *, seed: int, prompts_per_iter: int, vocab_size: int):
